@@ -315,6 +315,9 @@ def write_headline_file(
 def main() -> None:
     import importlib
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     only = sys.argv[1:] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
     failures = []

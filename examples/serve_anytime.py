@@ -46,6 +46,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import Engine, arrange, build_index
 from repro.core.anytime import Reactive, run_query_anytime
 from repro.core.metrics import rbo
@@ -365,6 +366,7 @@ def main():
                          "for `python -m repro.obs watch PATH`")
     args = ap.parse_args()
 
+    use_compile_cache()
     obs = (Instrumentation.make(sample_rate=1.0, trace_path=args.trace,
                                 profile=bool(args.metrics))
            if args.trace or args.metrics else NOOP)

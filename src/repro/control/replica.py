@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.sharding import replica_mesh
-from repro.serving.sharded import ShardedEngine, make_mesh_dispatch
+from repro.serving.sharded import ShardedEngine, make_mesh_dispatch, place_index
 
 __all__ = ["ReplicaGroupEngine"]
 
@@ -65,6 +65,13 @@ class ReplicaGroupEngine:
             if use_mesh
             else None
         )
+        if self.group_mesh is not None:
+            # The group's own copy of the index, placed once: each device
+            # holds its shard column's rows, replicated down the data axis.
+            self.group_dix, self.group_doc_base = place_index(
+                sengine.dix, sengine.doc_base, self.group_mesh, mesh_axis,
+                sengine.docs_format,
+            )
         self._group_fns: dict = {}
         self.dispatches = 0  # replica-mesh dispatches actually issued
 
@@ -115,13 +122,12 @@ class ReplicaGroupEngine:
                 safe_stop=safe_stop,
                 prune_blocks=prune_blocks,
                 impl=se.impl,
-                interpret=se.interpret,
                 data_axis=self._data_axis,
                 docs_format=se.docs_format,
             )
         out = self._group_fns[key](
-            self.sengine.dix,
-            self.sengine.doc_base,
+            self.group_dix,
+            self.group_doc_base,
             jnp.asarray(blk),
             jnp.asarray(rest),
             jnp.asarray(order),

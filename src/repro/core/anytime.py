@@ -155,7 +155,7 @@ def run_query_anytime(
 
     for i in range(n_ranges):
         th = int(np.asarray(theta(state)))
-        if safe_stop and th > 0 and plan.bounds_host[i] <= th:
+        if safe_stop and th > 0 and plan.bounds_host[i] < th:
             exit_reason = "safe"
             break
         elapsed = (clock() - t0) * 1e3

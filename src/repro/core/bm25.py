@@ -43,7 +43,7 @@ def checked_int32(arr: np.ndarray, what: str = "values") -> np.ndarray:
 
     Build-time counterpart of ``serving.bucketing.saturate_bounds``: a
     docid or bound that silently wraps negative disables the engine's
-    safe-termination test (``bound <= theta`` holds everywhere), so a
+    safe-termination test (``bound < theta`` holds everywhere), so a
     corpus past the int32 docid space must fail the build loudly, not
     corrupt the index.
     """

@@ -157,7 +157,7 @@ def merge_topk(vals: jnp.ndarray, ids: jnp.ndarray, k: int) -> tuple[jnp.ndarray
 
 @functools.partial(
     jax.jit,
-    static_argnames=("s_pad", "k", "impl", "prune_blocks", "interpret", "docs_format"),
+    static_argnames=("s_pad", "k", "impl", "prune_blocks", "docs_format"),
 )
 def score_range_step(
     dix: DeviceIndex,
@@ -170,7 +170,6 @@ def score_range_step(
     k: int,
     impl: str = "xla",
     prune_blocks: bool = True,
-    interpret: bool = True,
     docs_format: str = "int32",
 ) -> TopKState:
     """Score one range and merge its top-k into the running state."""
@@ -183,8 +182,10 @@ def score_range_step(
     if prune_blocks:
         # Block-level refinement (paper "Improved Pruning With Local Range
         # Bounds"): a block survives only if its own max impact plus the other
-        # terms' bounds can beat the current threshold.
-        keep = keep & (maximp + rest > th)
+        # terms' bounds can reach the current threshold. A doc that only ties
+        # the k-th score still enters the heap when its docid is smaller
+        # (score desc, docid asc), so equality must keep the block.
+        keep = keep & (maximp + rest >= th)
 
     pack_kw = {}
     if docs_format == "packed":
@@ -204,7 +205,6 @@ def score_range_step(
         range_start,
         s_pad=s_pad,
         impl=impl,
-        interpret=interpret,
         docs_format=docs_format,
         **pack_kw,
     )
@@ -223,7 +223,7 @@ def score_range_step(
 class TraverseResult(NamedTuple):
     state: TopKState
     ranges_processed: jnp.ndarray  # int32
-    exit_safe: jnp.ndarray  # bool — stopped because remaining bounds <= theta
+    exit_safe: jnp.ndarray  # bool — stopped because remaining bounds < theta
     exit_budget: jnp.ndarray  # bool — stopped by postings budget / fixed-n
 
 
@@ -314,7 +314,6 @@ def _traverse_loop(
     safe_stop: bool,
     prune_blocks: bool,
     impl: str,
-    interpret: bool,
     docs_format: str = "int32",
 ) -> TraverseCarry:
     """The one range-at-a-time while_loop both entry points share.
@@ -342,8 +341,10 @@ def _traverse_loop(
         r = order[i]
         bound = ordered_bounds[i]
         th = theta(state)
-        # Safe termination: every remaining range is bounded by this one.
-        s_safe = safe_stop & (bound <= th) & (th > 0)
+        # Safe termination: every remaining range is bounded by this one,
+        # strictly below theta — a remaining doc tied at theta with a
+        # smaller docid would still displace the k-th entry.
+        s_safe = safe_stop & (bound < th) & (th > 0)
         s_budget = (state.postings >= budget) | (i >= maxr)
         do = ~(s_safe | s_budget)
 
@@ -358,11 +359,13 @@ def _traverse_loop(
                 k=k,
                 impl=impl,
                 prune_blocks=prune_blocks,
-                interpret=interpret,
                 docs_format=docs_format,
             )
 
-        state = jax.lax.cond(do, run, lambda st: st, state)
+        # A select, not lax.cond: under vmap a cond on a per-lane predicate
+        # becomes a select anyway, and would first broadcast every closed-over
+        # operand, the whole index included, to one copy per lane.
+        state = jax.tree.map(functools.partial(jnp.where, do), run(state), state)
         return (steps + 1, i + jnp.where(do, 1, 0), state, s_safe, s_budget)
 
     c0 = (
@@ -379,7 +382,7 @@ def _traverse_loop(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "s_pad", "k", "impl", "prune_blocks", "safe_stop", "interpret",
+        "s_pad", "k", "impl", "prune_blocks", "safe_stop",
         "docs_format",
     ),
 )
@@ -397,7 +400,6 @@ def device_traverse(
     safe_stop: bool = True,
     prune_blocks: bool = True,
     impl: str = "xla",
-    interpret: bool = True,
     docs_format: str = "int32",
 ) -> TraverseResult:
     """Whole-query traversal in a lax.while_loop (device-side anytime mode)."""
@@ -416,7 +418,6 @@ def device_traverse(
         safe_stop=safe_stop,
         prune_blocks=prune_blocks,
         impl=impl,
-        interpret=interpret,
         docs_format=docs_format,
     )
     return carry_result(carry)
@@ -425,7 +426,7 @@ def device_traverse(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "s_pad", "k", "impl", "prune_blocks", "safe_stop", "interpret",
+        "s_pad", "k", "impl", "prune_blocks", "safe_stop",
         "docs_format",
     ),
 )
@@ -443,7 +444,6 @@ def batched_traverse(
     safe_stop: bool = True,
     prune_blocks: bool = True,
     impl: str = "xla",
-    interpret: bool = True,
     docs_format: str = "int32",
 ) -> TraverseResult:
     """vmapped ``device_traverse`` over a stacked batch of query plans.
@@ -471,7 +471,6 @@ def batched_traverse(
             safe_stop=safe_stop,
             prune_blocks=prune_blocks,
             impl=impl,
-            interpret=interpret,
             docs_format=docs_format,
         )
 
@@ -483,7 +482,7 @@ def batched_traverse(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "s_pad", "k", "quantum", "impl", "prune_blocks", "safe_stop", "interpret",
+        "s_pad", "k", "quantum", "impl", "prune_blocks", "safe_stop",
         "docs_format",
     ),
 )
@@ -503,7 +502,6 @@ def batched_traverse_resume(
     safe_stop: bool = True,
     prune_blocks: bool = True,
     impl: str = "xla",
-    interpret: bool = True,
     docs_format: str = "int32",
 ) -> TraverseCarry:
     """Resumable entry point: advance every lane at most ``quantum`` ranges.
@@ -536,7 +534,6 @@ def batched_traverse_resume(
             safe_stop=safe_stop,
             prune_blocks=prune_blocks,
             impl=impl,
-            interpret=interpret,
             docs_format=docs_format,
         )
 
@@ -626,7 +623,6 @@ class Engine:
         ordering: str = "boundsum",
         bounds: str = "range",
         impl: str = "xla",
-        interpret: bool = True,
         impact_dtype: str = "int32",
         docs_format: str = "int32",
         obs=None,
@@ -639,7 +635,6 @@ class Engine:
         self.ordering = ordering
         self.bounds = bounds
         self.impl = impl
-        self.interpret = interpret
         if impact_dtype not in IMPACT_DTYPES:
             raise ValueError(f"impact_dtype {impact_dtype!r} not in {IMPACT_DTYPES}")
         if impact_dtype == "int8" and index.quantizer.bits > 8:
@@ -727,7 +722,7 @@ class Engine:
         else:
             raise ValueError(f"unknown ordering {self.ordering!r}")
         # The device tables are int32; a BoundSum past 2^31 must saturate,
-        # not wrap — a negative bound satisfies `bound <= theta` immediately
+        # not wrap — a negative bound satisfies `bound < theta` immediately
         # and defeats safe termination. Saturation only errs conservative
         # (the traversal keeps going). The host copy keeps true int64 mass
         # for budget allocation (`query_shard_mass`).
@@ -761,7 +756,6 @@ class Engine:
             k=self.k,
             impl=self.impl,
             prune_blocks=True,
-            interpret=self.interpret,
             docs_format=self.docs_format,
         )
 
@@ -787,7 +781,6 @@ class Engine:
             safe_stop=safe_stop,
             prune_blocks=prune_blocks,
             impl=self.impl,
-            interpret=self.interpret,
             docs_format=self.docs_format,
         )
         if self.obs.enabled:

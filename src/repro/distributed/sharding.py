@@ -5,7 +5,6 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -13,8 +12,6 @@ __all__ = [
     "ShardCtx",
     "named",
     "data_spec",
-    "shard_map",
-    "axis_size",
     "replica_mesh",
     "retrieval_mesh",
 ]
@@ -34,9 +31,7 @@ def retrieval_mesh(n_shards: int, axis: str = "shard") -> Mesh:
             "set XLA_FLAGS=--xla_force_host_platform_device_count before "
             "importing jax, or use the single-device vmap path"
         )
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh((n_shards,), (axis,))
-    return Mesh(np.asarray(jax.devices()[:n_shards]), (axis,))
+    return jax.make_mesh((n_shards,), (axis,), devices=jax.devices()[:n_shards])
 
 
 def replica_mesh(
@@ -58,46 +53,10 @@ def replica_mesh(
             f"have {n_dev}; set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count before importing jax, or drop to 1 replica"
         )
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh((n_replicas, n_shards), (data_axis, shard_axis))
-    return Mesh(
-        np.asarray(jax.devices()[:need]).reshape(n_replicas, n_shards),
-        (data_axis, shard_axis),
+    return jax.make_mesh(
+        (n_replicas, n_shards), (data_axis, shard_axis),
+        devices=jax.devices()[:need],
     )
-
-
-def axis_size(name):
-    """Mesh-axis size inside a mapped body; pre-0.5 jax lacks lax.axis_size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """``jax.shard_map`` with fallback to the pre-0.5 experimental API.
-
-    Callers use the modern keyword surface (``axis_names`` = the manual mesh
-    axes, ``check_vma``); on older jax this translates to
-    ``jax.experimental.shard_map.shard_map`` where the equivalents are
-    ``auto`` (the *complement*: axes left automatic) and ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,7 +2,8 @@
 
 ``score_blocks`` is the one entry point the traversal engine calls; ``impl``
 selects the XLA scatter path (fast on CPU, the oracle) or the Pallas one-hot
-MXU kernel (the TPU target, validated in interpret mode). ``docs_format``
+MXU kernel, which ``kernel.pallas_interpret`` interprets on CPU and compiles
+on TPU. ``docs_format``
 selects how block docids reach the scorer: ``"int32"`` gathers the raw docid
 array, ``"packed"`` decodes per-block bit-packed deltas in place
 (DESIGN.md §12) — the two are bitwise-identical by contract.
@@ -28,7 +29,7 @@ __all__ = ["DOCS_FORMATS", "IMPACT_BIAS", "score_blocks"]
 
 
 @functools.partial(
-    jax.jit, static_argnames=("s_pad", "impl", "interpret", "docs_format")
+    jax.jit, static_argnames=("s_pad", "impl", "docs_format")
 )
 def score_blocks(
     post_docs: jnp.ndarray,
@@ -40,7 +41,6 @@ def score_blocks(
     *,
     s_pad: int,
     impl: str = "xla",
-    interpret: bool = True,
     docs_format: str = "int32",
     pack_words: jnp.ndarray | None = None,
     pack_dir: jnp.ndarray | None = None,
@@ -74,7 +74,6 @@ def score_blocks(
             local = unpack_locals_pallas(
                 pack_words, starts, lens,
                 pack_dir, pack_firsts, keep, range_start,
-                interpret=interpret,
             )
             valid = ref._lane_valid(starts, lens, keep)
             v = ref.gather_block_impacts(post_imps, starts)
@@ -83,7 +82,5 @@ def score_blocks(
             local, vals = ref.gather_block_postings(
                 post_docs, post_imps, starts, lens, keep, range_start
             )
-        return scatter_accumulate_pallas(
-            local, vals, s_pad=s_pad, interpret=interpret
-        )
+        return scatter_accumulate_pallas(local, vals, s_pad=s_pad)
     raise ValueError(f"unknown impl {impl!r}")

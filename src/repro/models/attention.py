@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.sharding import axis_size, shard_map
-
 from repro.models.layers import apply_rope, dense_init, rms_norm, rms_norm_init
 
 __all__ = ["GQAConfig", "MLAConfig", "init_gqa", "gqa", "init_mla", "mla"]
@@ -364,7 +362,7 @@ def gqa_decode_splitkv(
     # ALL-manual shard_map (every mesh axis listed): bf16 psum under
     # partial-manual shard_map hits an XLA-CPU partitioner crash
     # ("Invalid binary instruction opcode copy") — recorded in §Perf.
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=shard_ctx.mesh,
         in_specs=(p_specs, P(da, m_axis, None, None),
@@ -466,7 +464,7 @@ def mla_decode_splitkv(
         "wo": P(None, m_axis),
     }
     da = shard_ctx.data_axes
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=shard_ctx.mesh,
         in_specs=(p_specs, P(da, m_axis, None), P(da, m_axis, None),
@@ -521,7 +519,7 @@ def gqa_prefill_splitkv(
         k = apply_rope(k, rope_table, positions)
 
         # Chunk C == S_loc: rank c_idx owns the whole write.
-        mine = (c_idx % axis_size(m_axis)) == m
+        mine = (c_idx % jax.lax.axis_size(m_axis)) == m
         k_cache = jnp.where(mine, k.astype(k_cache.dtype), k_cache)
         v_cache = jnp.where(mine, v.astype(v_cache.dtype), v_cache)
 
@@ -567,7 +565,7 @@ def gqa_prefill_splitkv(
         p_specs.update({"bq": P(), "bk": P(), "bv": P()})
     if cfg.qk_norm:
         p_specs.update({"q_norm": P(), "k_norm": P()})
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=shard_ctx.mesh,
         in_specs=(p_specs, P(da, m_axis, None, None),

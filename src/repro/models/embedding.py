@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 __all__ = ["embedding_bag", "embedding_bag_ragged", "sharded_field_lookup"]
 
 
@@ -94,7 +92,7 @@ def sharded_field_lookup(table, ids, shard_ctx):
     for a in shard_ctx.data_axes:
         n_data *= shard_ctx.mesh.shape[a]
     ids_spec = P(shard_ctx.data_axes) if flat.shape[0] % n_data == 0 else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=shard_ctx.mesh,
         in_specs=(P(m_axis, None), ids_spec),

@@ -18,8 +18,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard_map
-
 from repro.models.layers import dense_init
 
 __all__ = ["SAGEConfig", "init_sage", "sage_forward", "sage_forward_sampled", "sage_forward_graphs", "sage_param_specs"]
@@ -196,7 +194,7 @@ def sage_forward_sharded(params, feats_loc, agg0_loc, edges_loc,
             h = _sage_layer(layer, h, agg, i == L - 1)
         return h
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=shard_ctx.mesh,
         in_specs=(P(), P(da, None), P(da, None), P(da, None)),

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 from repro.models.layers import dense_init
 
 __all__ = ["MoEConfig", "init_moe", "moe_ffn"]
@@ -175,7 +173,7 @@ def moe_ffn(params, x2d: jnp.ndarray, cfg: MoEConfig, shard_ctx=None):
             "w_up": P(None, model_axis),
             "w_down": P(model_axis, None),
         }
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=shard_ctx.mesh,
         in_specs=(param_specs, P(data_axes, None)),
@@ -244,7 +242,7 @@ def moe_ffn_decode_ep_all(params, x2d: jnp.ndarray, cfg: MoEConfig, shard_ctx):
             "w_up": P(None, shard_ctx.model_axis),
             "w_down": P(shard_ctx.model_axis, None),
         }
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=shard_ctx.mesh,
         in_specs=(param_specs, P("data", None)),

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import axis_size, shard_map
-
 __all__ = ["compress_psum_pod", "init_error_buffers"]
 
 _BLOCK = 256
@@ -64,7 +62,7 @@ def compress_psum_pod(grad_fn, mesh, pod_axis: str = "pod"):
 
     def body(batch_shard, err):
         g = grad_fn(batch_shard)
-        n_pods = axis_size(pod_axis)
+        n_pods = jax.lax.axis_size(pod_axis)
 
         def one(gl, el):
             el = el[0]  # leading pod dim -> local slice
@@ -91,7 +89,7 @@ def compress_psum_pod(grad_fn, mesh, pod_axis: str = "pod"):
     # ALL-manual (every mesh axis listed): pod-only partial-manual hits the
     # same XLA-CPU partitioner crash as the attention psums. Non-pod axes are
     # simply unreferenced in the body, so the collective pattern is unchanged.
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(pod_axis), P(pod_axis)),

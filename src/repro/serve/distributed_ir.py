@@ -35,7 +35,7 @@ from repro.core import range_daat
 from repro.core.clustered_index import build_index
 from repro.core.range_daat import DeviceIndex
 from repro.data.synth import Corpus
-from repro.distributed.sharding import ShardCtx, shard_map
+from repro.distributed.sharding import ShardCtx
 
 __all__ = ["ShardedIndexArrays", "build_sharded_index", "sharded_anytime_query", "sharded_query_specs"]
 
@@ -210,7 +210,7 @@ def _local_traverse(arrays_local, blk, rest, order, bounds, *, s_pad, k,
     res = range_daat.device_traverse(
         dix, blk, rest, order, bounds,
         s_pad=s_pad, k=k, budget_postings=budget, safe_stop=True,
-        prune_blocks=prune_blocks, impl="xla", interpret=True,
+        prune_blocks=prune_blocks, impl="xla",
     )
     return res.state.vals, res.state.ids, res.ranges_processed
 
@@ -247,7 +247,7 @@ def make_sharded_query_fn(ctx: ShardCtx, *, s_pad: int, k: int, budget: int):
         return out_v, out_i, jax.lax.psum(jnp.sum(nr), m_axis)
 
     arr_specs = tuple([P(m_axis, None)] * 6)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(

@@ -210,7 +210,6 @@ class BatchEngine:
             safe_stop=safe_stop,
             prune_blocks=prune_blocks,
             impl=eng.impl,
-            interpret=eng.interpret,
             docs_format=eng.docs_format,
         )
         self.compiled_shapes.add((batch, width))

@@ -62,7 +62,7 @@ def saturate_bounds(bounds_host: np.ndarray) -> np.ndarray:
     """Narrow int64 per-range BoundSums to the device's int32 lattice.
 
     A BoundSum past 2^31 must *saturate*, never wrap: a wrapped-negative
-    bound satisfies ``bound <= theta`` immediately and silently disables
+    bound satisfies ``bound < theta`` immediately and silently disables
     safe termination for that range. Saturation errs conservative (the
     range merely looks too promising to skip).
     """

@@ -257,7 +257,6 @@ class InflightServer:
             k=eng.k,
             quantum=self.quantum,
             impl=eng.impl,
-            interpret=eng.interpret,
             docs_format=eng.docs_format,
         )
         self.compiled_shapes.add((self.n_slots, front.width))

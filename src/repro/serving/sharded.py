@@ -36,6 +36,8 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.clustered_index import (
     BLOCK,
@@ -53,7 +55,7 @@ from repro.core.range_daat import (
     merge_topk,
     pack_impacts,
 )
-from repro.distributed.sharding import retrieval_mesh, shard_map
+from repro.distributed.sharding import retrieval_mesh
 from repro.obs.profiler import jit_cache_size
 from repro.serving.bucketing import (
     BucketSpec,
@@ -68,6 +70,8 @@ __all__ = [
     "ShardedBatchEngine",
     "ShardedResult",
     "apply_down_mask",
+    "index_specs",
+    "place_index",
     "sharded_batched_traverse",
     "shard_exit_reason",
 ]
@@ -91,7 +95,7 @@ def _merge_gathered(vals, gids, k):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "s_pad", "k", "safe_stop", "prune_blocks", "impl", "interpret",
+        "s_pad", "k", "safe_stop", "prune_blocks", "impl",
         "docs_format",
     ),
 )
@@ -110,7 +114,6 @@ def sharded_batched_traverse(
     safe_stop: bool = True,
     prune_blocks: bool = True,
     impl: str = "xla",
-    interpret: bool = True,
     docs_format: str = "int32",
 ):
     """(batch x shard) traversal on one device: vmap over both axes.
@@ -133,7 +136,6 @@ def sharded_batched_traverse(
             safe_stop=safe_stop,
             prune_blocks=prune_blocks,
             impl=impl,
-            interpret=interpret,
             docs_format=docs_format,
         )
 
@@ -156,6 +158,40 @@ def sharded_batched_traverse(
     )
 
 
+def index_specs(axis: str, docs_format: str) -> DeviceIndex:
+    """PartitionSpecs of a stacked [S, ...] ``DeviceIndex``: the shard axis
+    over mesh ``axis``, every other dim (and any other mesh axis, such as
+    a replica axis) replicated."""
+    row = P(axis, None)
+    pack = {}
+    if docs_format == "packed":
+        pack = dict(pack_words=row, pack_dir=row, pack_first=row)
+    return DeviceIndex(
+        docs=row,
+        impacts=row,
+        blk_start=row,
+        blk_len=row,
+        blk_maximp=row,
+        bounds_dense=P(axis, None, None),
+        range_starts=row,
+        range_sizes=row,
+        **pack,
+    )
+
+
+def place_index(dix: DeviceIndex, doc_base, mesh, axis: str, docs_format: str):
+    """Put a stacked host index on ``mesh``, one shard per ``axis`` slot.
+
+    Each device receives only its own shard's rows (replicated across any
+    other mesh axis), once, so a mesh dispatch never reshards the index.
+    """
+    specs = index_specs(axis, docs_format)
+    return (
+        jax.device_put(dix, jax.tree.map(lambda p: NamedSharding(mesh, p), specs)),
+        jax.device_put(doc_base, NamedSharding(mesh, P(axis))),
+    )
+
+
 def make_mesh_dispatch(
     mesh,
     axis: str,
@@ -165,15 +201,14 @@ def make_mesh_dispatch(
     safe_stop: bool,
     prune_blocks: bool,
     impl: str,
-    interpret: bool,
     data_axis: str | None = None,
     docs_format: str = "int32",
 ):
     """Compile the (batch x shard) step with one shard per mesh device.
 
     Same input/output contract as ``sharded_batched_traverse``; the shard
-    axis is laid over the mesh via the ``distributed.sharding.shard_map``
-    wrapper and the broker merge is an ``all_gather`` + lexsort top-k inside
+    axis is laid over the mesh via ``jax.shard_map`` (the index placed by
+    ``place_index``) and the broker merge is an ``all_gather`` + lexsort top-k inside
     the compiled program, so one dispatch serves the whole batch on all
     shards (DESIGN.md §4).
 
@@ -184,8 +219,6 @@ def make_mesh_dispatch(
     The per-query math is untouched, so an N-replica dispatch is bitwise
     identical to running the same queries on one replica.
     """
-    from jax.sharding import PartitionSpec as P
-
     def body(dix, doc_base, blk, rest, order, bounds, budgets, maxr):
         dix1 = jax.tree.map(lambda a: a[0], dix)  # local shard, leading 1 off
         base = doc_base[0]
@@ -204,7 +237,6 @@ def make_mesh_dispatch(
                 safe_stop=safe_stop,
                 prune_blocks=prune_blocks,
                 impl=impl,
-                interpret=interpret,
                 docs_format=docs_format,
             )
 
@@ -228,30 +260,12 @@ def make_mesh_dispatch(
             diag(res.exit_budget),
         )
 
-    pack_specs = {}
-    if docs_format == "packed":
-        pack_specs = dict(
-            pack_words=P(axis, None),
-            pack_dir=P(axis, None),
-            pack_first=P(axis, None),
-        )
-    dix_specs = DeviceIndex(
-        docs=P(axis, None),
-        impacts=P(axis, None),
-        blk_start=P(axis, None),
-        blk_len=P(axis, None),
-        blk_maximp=P(axis, None),
-        bounds_dense=P(axis, None, None),
-        range_starts=P(axis, None),
-        range_sizes=P(axis, None),
-        **pack_specs,
-    )
     da = data_axis  # None -> batch replicated on every shard device (§4)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
-            dix_specs,
+            index_specs(axis, docs_format),
             P(axis),
             P(da, axis, None, None),
             P(da, axis, None, None),
@@ -370,7 +384,6 @@ class ShardedEngine:
         self.k = engine.k
         self.s_pad = engine.s_pad
         self.impl = engine.impl
-        self.interpret = engine.interpret
         self.impact_dtype = engine.impact_dtype
         self.docs_format = engine.docs_format
         if shards is None:
@@ -398,7 +411,7 @@ class ShardedEngine:
             out = np.full((self.n_shards, w), pad, arrs[0].dtype if arrs else np.int32)
             for s, a in enumerate(arrs):
                 out[s, : a.shape[0]] = a
-            return jnp.asarray(out)
+            return out
 
         # bounds_dense is a planning-time structure; traversal reads bounds
         # via the plan tables, so the device mirror carries a placeholder
@@ -416,7 +429,7 @@ class ShardedEngine:
                 pack_docs(sh.docs, sh.blk_start, sh.blk_len)
                 for sh in self.shards
             ]
-            docs_dev = jnp.zeros((self.n_shards, 1), jnp.int32)
+            docs_dev = np.zeros((self.n_shards, 1), np.int32)
             pack_dev = dict(
                 pack_words=stack(
                     "words", arrs=[np.asarray(p.words, np.uint32) for p in packed]
@@ -431,7 +444,7 @@ class ShardedEngine:
         else:
             docs_dev = stack("docs")
             pack_dev = {}
-        self.dix = DeviceIndex(
+        host = DeviceIndex(
             docs=docs_dev,
             impacts=stack(
                 "impacts",
@@ -443,16 +456,23 @@ class ShardedEngine:
             blk_start=stack("blk_start"),
             blk_len=stack("blk_len"),
             blk_maximp=stack("blk_maximp"),
-            bounds_dense=jnp.zeros((self.n_shards, 1, 1), jnp.int32),
+            bounds_dense=np.zeros((self.n_shards, 1, 1), np.int32),
             range_starts=stack("range_starts"),
             range_sizes=stack("range_sizes"),
             **pack_dev,
         )
-        self.doc_base = jnp.asarray(self.doc_base_host, jnp.int32)
+        doc_base = self.doc_base_host.astype(np.int32)
 
         if use_mesh is None:
             use_mesh = self.n_shards > 1 and jax.device_count() >= self.n_shards
         self.mesh = retrieval_mesh(self.n_shards, mesh_axis) if use_mesh else None
+        if self.mesh is None:
+            self.dix = jax.tree.map(jnp.asarray, host)
+            self.doc_base = jnp.asarray(doc_base)
+        else:
+            self.dix, self.doc_base = place_index(
+                host, doc_base, self.mesh, mesh_axis, self.docs_format
+            )
         self._mesh_axis = mesh_axis
         self._mesh_fns: dict = {}
 
@@ -608,7 +628,6 @@ class ShardedEngine:
                     safe_stop=safe_stop,
                     prune_blocks=prune_blocks,
                     impl=self.impl,
-                    interpret=self.interpret,
                     docs_format=self.docs_format,
                 )
             fn = self._mesh_fns[key]
@@ -621,7 +640,6 @@ class ShardedEngine:
                 safe_stop=safe_stop,
                 prune_blocks=prune_blocks,
                 impl=self.impl,
-                interpret=self.interpret,
                 docs_format=self.docs_format,
             )
         if prof is None:
@@ -725,8 +743,9 @@ class ShardedEngine:
             # Exactness certificate, strict about tie-breaks: a doc in a
             # skipped range can score up to that range's BoundSum, and at
             # equal score a smaller docid displaces the k-th entry under the
-            # heap's total order — so the device's non-strict safe condition
-            # (bound <= theta) is not enough by itself. Exact iff no skipped
+            # heap's total order. The device's safe condition (bound < theta)
+            # is judged per shard and per lane, so the certificate is
+            # re-derived here against the merged list. Exact iff no skipped
             # range could hold a scoring doc (resid == 0; covers exhausted
             # shards and empty-for-query skipped ranges), or the list is
             # FULL and every skipped range is strictly below the k-th score.

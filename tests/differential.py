@@ -53,7 +53,6 @@ class EngineConfig:
     impact_dtype: str = "int32"
     docs_format: str = "int32"
     impl: str = "xla"
-    interpret: bool = True
     n_shards: int = 1
 
     def describe(self) -> str:
@@ -69,7 +68,6 @@ def build_engine(index: ClusteredIndex, cfg: EngineConfig, k: int = 5):
         index,
         k=k,
         impl=cfg.impl,
-        interpret=cfg.interpret,
         impact_dtype=cfg.impact_dtype,
         docs_format=cfg.docs_format,
     )

@@ -466,8 +466,9 @@ def test_replica_mesh_dispatch_threads_docs_format(monkeypatch):
     monkeypatch.setattr(
         replica_mod, "make_mesh_dispatch", fake_make_mesh_dispatch
     )
+    monkeypatch.setattr(replica_mod, "place_index", lambda *a: (None, None))
     se = SimpleNamespace(
-        n_shards=1, s_pad=4, k=8, impl="jax", interpret=False,
+        n_shards=1, s_pad=4, k=8, impl="jax",
         docs_format="packed", dix=None, doc_base=None, obs=NOOP,
     )
     eng = replica_mod.ReplicaGroupEngine(se, n_replicas=1, use_mesh=True)

@@ -5,7 +5,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.embedding_bag.ops import bag_reduce
 from repro.models.embedding import embedding_bag, embedding_bag_ragged

@@ -1,7 +1,9 @@
 """Pallas range-scorer kernel vs pure-jnp oracle: shape/dtype sweeps.
 
-The kernel runs in interpret mode (CPU container; TPU is the target). All
-comparisons are exact — integer impacts accumulated in fp32 stay below 2^24.
+On CPU the kernels run in interpret mode (``kernel.pallas_interpret``);
+``tests/test_tpu_compile.py`` compiles them for a described v5e, and
+``chip_smoke.py`` checks them compiled on the chip. All comparisons are
+exact — integer impacts accumulated in fp32 stay below 2^24.
 """
 
 from __future__ import annotations
@@ -9,7 +11,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.clustered_index import pack_dir_entries, pack_docs
 from repro.kernels.range_scorer import ref
@@ -90,55 +92,6 @@ def test_padding_blocks_ignored():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.slow
-def test_compiled_pallas_backend_smoke():
-    """Tier-2 de-risk: the kernel with ``interpret=False`` on a compiled
-    Pallas backend (TPU/GPU), skip-guarded on CPU where only interpret mode
-    exists. The flag is plumbed through ``Engine(impl="pallas",
-    interpret=False)``, so the full compiled path is this one switch."""
-    import jax
-
-    if jax.default_backend() == "cpu":
-        pytest.skip("no compiled Pallas backend on CPU (interpret-only)")
-
-    # Kernel level: compiled == oracle scatter.
-    rng = np.random.default_rng(0)
-    P, S = 4000, 1024
-    ids = rng.integers(-1, S, size=P).astype(np.int32)
-    vals = rng.integers(0, 256, size=P).astype(np.int32)
-    vals[ids < 0] = 0
-    got = scatter_accumulate_pallas(
-        jnp.asarray(ids), jnp.asarray(vals), s_pad=S, interpret=False
-    )
-    expect = np.zeros(S, np.int64)
-    np.add.at(expect, ids[ids >= 0], vals[ids >= 0])
-    np.testing.assert_array_equal(np.asarray(got, np.int64), expect)
-
-    # Engine level: the compiled Pallas scorer is one switch away and
-    # bitwise-identical to the XLA reference over whole-query traversals.
-    from repro.core.clustered_index import build_index
-    from repro.core.range_daat import Engine
-    from repro.data.synth import make_corpus, make_query_log
-
-    corpus = make_corpus(
-        n_docs=900, n_terms=700, n_topics=4, mean_doc_len=50, seed=5
-    )
-    idx = build_index(corpus, n_ranges=6, strategy="clustered")
-    ref_eng = Engine(idx, k=10, impl="xla")
-    compiled = Engine(idx, k=10, impl="pallas", interpret=False)
-    assert compiled.interpret is False
-    log = make_query_log(corpus, n_queries=6, seed=6)
-    for i in range(log.n_queries):
-        plan_r = ref_eng.plan(log.terms[i])
-        plan_c = compiled.plan(log.terms[i])
-        a = ref_eng.traverse(plan_r)
-        b = compiled.traverse(plan_c)
-        rids, rvals = ref_eng.topk_docs(a.state)
-        cids, cvals = compiled.topk_docs(b.state)
-        assert cids.tolist() == rids.tolist()
-        assert cvals.tolist() == rvals.tolist()
-
-
 # --------------------------------------------------- packed docid decoding
 
 
@@ -169,15 +122,21 @@ def _select(packed, blk_start, blk_len, sel):
     )
 
 
-@pytest.mark.parametrize("seed", [0, 3, 9])
-def test_pallas_packed_decode_matches_oracle_across_widths(seed):
-    """Kernel decode == pure-jnp oracle for every width in one dispatch."""
+@pytest.mark.parametrize(
+    "seed,n_pool,n_sel", [(0, 24, 17), (3, 24, 17), (9, 24, 17), (4, 400, 64)]
+)
+def test_pallas_packed_decode_matches_oracle_across_widths(seed, n_pool, n_sel):
+    """Kernel decode == pure-jnp oracle for every width in one dispatch.
+
+    The 400-block pool spans many 1024-word tiles, so block runs start at
+    every offset and cross 128-word row and tile boundaries.
+    """
     rng = np.random.default_rng(seed)
-    _, imps, blk_start, blk_len, packed = _packed_pool(rng, 24)
+    _, imps, blk_start, blk_len, packed = _packed_pool(rng, n_pool)
     assert {0, 4, 8, 16, 32} <= set(packed.blk_width.tolist())
-    sel = rng.integers(0, 24, size=17)  # duplicates allowed, like a query
+    sel = rng.integers(0, n_pool, size=n_sel)  # duplicates allowed, like a query
     cols = _select(packed, blk_start, blk_len, sel)
-    keep = jnp.asarray(rng.random(17) < 0.8)
+    keep = jnp.asarray(rng.random(n_sel) < 0.8)
     words = jnp.asarray(packed.words)
     r0 = jnp.int32(int(rng.integers(0, 100)))
     oracle_local, _ = ref.gather_block_postings_packed(

@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.clustered_index import (
     BLOCK,

@@ -12,7 +12,7 @@ correctness rests on:
 from __future__ import annotations
 
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core.clustered_index import build_index
 from repro.core.oracle import exhaustive_scores, exhaustive_topk
@@ -41,6 +41,9 @@ def test_boundsum_is_admissible(seed, n_ranges):
 
 @settings(max_examples=6, deadline=None)
 @given(seed=st.integers(0, 2**30))
+# Seed 103: a doc tied at the k-th score with a smaller docid sits in a
+# later range; non-strict pruning / safe stop used to drop it.
+@example(seed=103)
 def test_safe_traversal_safe_on_random_corpora(seed):
     corpus = make_corpus(n_docs=250, n_terms=250, n_topics=3,
                          mean_doc_len=30, seed=seed % 1000)
