@@ -1,0 +1,27 @@
+"""Published peaks of each chip the benchmark runs on, keyed by ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s HBM bandwidth, 16 GB HBM per chip.
+A device that is not in the table is an error, never a default.
+"""
+
+from __future__ import annotations
+
+__all__ = ["PEAKS", "peak"]
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+def peak(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}") from None
