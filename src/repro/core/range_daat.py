@@ -172,30 +172,37 @@ def score_range_step(
     prune_blocks: bool = True,
     docs_format: str = "int32",
 ) -> TopKState:
-    """Score one range and merge its top-k into the running state."""
-    th = theta(state)
-    safe_ids = jnp.clip(blk_ids, 0).astype(jnp.int32)
-    starts = dix.blk_start[safe_ids]
-    lens = dix.blk_len[safe_ids]
-    maximp = dix.blk_maximp[safe_ids]
-    keep = blk_ids >= 0
-    if prune_blocks:
-        # Block-level refinement (paper "Improved Pruning With Local Range
-        # Bounds"): a block survives only if its own max impact plus the other
-        # terms' bounds can reach the current threshold. A doc that only ties
-        # the k-th score still enters the heap when its docid is smaller
-        # (score desc, docid asc), so equality must keep the block.
-        keep = keep & (maximp + rest >= th)
+    """Score one range and merge its top-k into the running state.
 
-    pack_kw = {}
-    if docs_format == "packed":
-        # Per-block packed directory rows travel with the block table; the
-        # shared word stream goes through whole (DESIGN.md §12).
-        pack_kw = dict(
-            pack_words=dix.pack_words,
-            pack_dir=dix.pack_dir[safe_ids],
-            pack_firsts=dix.pack_first[safe_ids],
-        )
+    Its device work carries three named scopes, the layers a profiler trace
+    splits it by: ``gather`` (block metadata, the prune mask, postings),
+    ``accumulate`` (the scatter into the range accumulator) and ``topk``
+    (the range's top-k and the merge into the heap).
+    """
+    th = theta(state)
+    with jax.named_scope("gather"):
+        safe_ids = jnp.clip(blk_ids, 0).astype(jnp.int32)
+        starts = dix.blk_start[safe_ids]
+        lens = dix.blk_len[safe_ids]
+        maximp = dix.blk_maximp[safe_ids]
+        keep = blk_ids >= 0
+        if prune_blocks:
+            # Block-level refinement (paper "Improved Pruning With Local Range
+            # Bounds"): a block survives only if its own max impact plus the
+            # other terms' bounds can reach the current threshold. A doc that
+            # only ties the k-th score still enters the heap when its docid is
+            # smaller (score desc, docid asc), so equality must keep the block.
+            keep = keep & (maximp + rest >= th)
+
+        pack_kw = {}
+        if docs_format == "packed":
+            # Per-block packed directory rows travel with the block table; the
+            # shared word stream goes through whole (DESIGN.md §12).
+            pack_kw = dict(
+                pack_words=dix.pack_words,
+                pack_dir=dix.pack_dir[safe_ids],
+                pack_firsts=dix.pack_first[safe_ids],
+            )
     acc = scorer_ops.score_blocks(
         dix.docs,
         dix.impacts,
@@ -209,9 +216,12 @@ def score_range_step(
         **pack_kw,
     )
 
-    vals, loc = jax.lax.top_k(acc, k)
-    cand_ids = jnp.where(vals > 0, loc.astype(jnp.int32) + range_start, -1)
-    nv, ni = _merge_topk(state.vals, state.ids, vals.astype(jnp.int32), cand_ids, k)
+    with jax.named_scope("topk"):
+        vals, loc = jax.lax.top_k(acc, k)
+        cand_ids = jnp.where(vals > 0, loc.astype(jnp.int32) + range_start, -1)
+        nv, ni = _merge_topk(
+            state.vals, state.ids, vals.astype(jnp.int32), cand_ids, k
+        )
     return TopKState(
         vals=nv,
         ids=ni,

@@ -135,6 +135,7 @@ def scatter_accumulate_pallas(
         out_specs=pl.BlockSpec((1, s_tile), lambda s, p: (0, s)),
         out_shape=jax.ShapeDtypeStruct((1, sp), jnp.float32),
         interpret=pallas_interpret(),
+        name="scatter_accumulate",
     )(ids.reshape(1, pp), vals.reshape(1, pp))
     return acc[0, :s_pad].astype(jnp.int32)
 
@@ -286,5 +287,6 @@ def unpack_locals_pallas(
             pltpu.SemaphoreType.DMA((b_tile,)),
         ],
         interpret=pallas_interpret(),
+        name="unpack_locals",
     )(table, words)
     return out[:B].reshape(B * BLOCK)

@@ -6,7 +6,9 @@ MXU kernel, which ``kernel.pallas_interpret`` interprets on CPU and compiles
 on TPU. ``docs_format``
 selects how block docids reach the scorer: ``"int32"`` gathers the raw docid
 array, ``"packed"`` decodes per-block bit-packed deltas in place
-(DESIGN.md §12) — the two are bitwise-identical by contract.
+(DESIGN.md §12) — the two are bitwise-identical by contract. Both paths
+run their posting gathers under the named scope ``gather`` and their
+accumulation under ``accumulate``.
 """
 
 from __future__ import annotations
@@ -71,16 +73,19 @@ def score_blocks(
         )
     if impl == "pallas":
         if docs_format == "packed":
-            local = unpack_locals_pallas(
-                pack_words, starts, lens,
-                pack_dir, pack_firsts, keep, range_start,
-            )
-            valid = ref._lane_valid(starts, lens, keep)
-            v = ref.gather_block_impacts(post_imps, starts)
-            vals = jnp.where(valid, v, 0).astype(jnp.int32).reshape(local.shape)
+            with jax.named_scope("gather"):
+                local = unpack_locals_pallas(
+                    pack_words, starts, lens,
+                    pack_dir, pack_firsts, keep, range_start,
+                )
+                valid = ref._lane_valid(starts, lens, keep)
+                v = ref.gather_block_impacts(post_imps, starts)
+                vals = jnp.where(valid, v, 0).astype(jnp.int32).reshape(local.shape)
         else:
-            local, vals = ref.gather_block_postings(
-                post_docs, post_imps, starts, lens, keep, range_start
-            )
-        return scatter_accumulate_pallas(local, vals, s_pad=s_pad)
+            with jax.named_scope("gather"):
+                local, vals = ref.gather_block_postings(
+                    post_docs, post_imps, starts, lens, keep, range_start
+                )
+        with jax.named_scope("accumulate"):
+            return scatter_accumulate_pallas(local, vals, s_pad=s_pad)
     raise ValueError(f"unknown impl {impl!r}")

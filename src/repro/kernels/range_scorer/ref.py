@@ -3,11 +3,14 @@
 Scores one document range: gathers the postings of the query's surviving
 blocks and scatter-adds quantized impacts into a range-local accumulator.
 This is the semantic reference the Pallas kernel must match exactly
-(integer impacts; float32 accumulation is exact below 2^24).
+(integer impacts; float32 accumulation is exact below 2^24). The posting
+gathers run under the named scope ``gather`` and the scatter-add under
+``accumulate``, so a profiler trace times the two apart.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.clustered_index import PACK_DIR_BITS, PACK_WIDTHS
@@ -156,13 +159,15 @@ def score_blocks_ref(
     s_pad: int,
 ) -> jnp.ndarray:
     """Accumulate surviving blocks into an int32 accumulator of size s_pad."""
-    local, vals = gather_block_postings(
-        post_docs, post_imps, starts, lens, keep, range_start
-    )
-    # local == -1 -> clamp to s_pad and drop via mode="drop".
-    tgt = jnp.where(local < 0, s_pad, local)
-    acc = jnp.zeros((s_pad,), jnp.int32)
-    return acc.at[tgt].add(vals, mode="drop")
+    with jax.named_scope("gather"):
+        local, vals = gather_block_postings(
+            post_docs, post_imps, starts, lens, keep, range_start
+        )
+    with jax.named_scope("accumulate"):
+        # local == -1 -> clamp to s_pad and drop via mode="drop".
+        tgt = jnp.where(local < 0, s_pad, local)
+        acc = jnp.zeros((s_pad,), jnp.int32)
+        return acc.at[tgt].add(vals, mode="drop")
 
 
 def score_blocks_packed_ref(
@@ -177,10 +182,12 @@ def score_blocks_packed_ref(
     s_pad: int,
 ) -> jnp.ndarray:
     """Packed-format twin of :func:`score_blocks_ref` (same accumulator)."""
-    local, vals = gather_block_postings_packed(
-        pack_words, post_imps, starts, lens,
-        pack_dir, pack_firsts, keep, range_start,
-    )
-    tgt = jnp.where(local < 0, s_pad, local)
-    acc = jnp.zeros((s_pad,), jnp.int32)
-    return acc.at[tgt].add(vals, mode="drop")
+    with jax.named_scope("gather"):
+        local, vals = gather_block_postings_packed(
+            pack_words, post_imps, starts, lens,
+            pack_dir, pack_firsts, keep, range_start,
+        )
+    with jax.named_scope("accumulate"):
+        tgt = jnp.where(local < 0, s_pad, local)
+        acc = jnp.zeros((s_pad,), jnp.int32)
+        return acc.at[tgt].add(vals, mode="drop")

@@ -29,18 +29,17 @@ METRIC_HELP: dict[str, str] = {
     # ------------------------------------------------------------- servers
     "submitted": "Queries submitted to a server, by server label.",
     "admissions": "Queries admitted into in-flight slots.",
-    "parks": "Queries parked while all in-flight slots were busy.",
     "budget_postings": "Finite admission postings budgets (sentinel-free).",
     "unlimited_admissions": "Admissions with an unlimited (inf-SLA) budget.",
     "batch_size": "Queries per drained micro-batch.",
     "batch_ms": "Wall-clock per micro-batch dispatch.",
     "step_ms": "Wall-clock per in-flight quantum step.",
-    "active_lanes": "Live lanes per in-flight step.",
     "slot_occupancy": "Occupied in-flight slots after the latest step.",
     "queue_depth": "Queries waiting in the server queue, by server.",
     "served_queries": "Completed queries, by server and exit reason.",
     "latency_ms": "End-to-end query latency (submit to serve), by server.",
     "quanta": "Resume quanta a query lived through before completing.",
+    "phase_ms": "Wall-clock per phase of a server step, by server and phase.",
     # ------------------------------------------------------------- sharded
     "sharded_queries": "Queries served through the sharded broker.",
     "shard_exits": "Per-shard exit reasons across sharded queries.",

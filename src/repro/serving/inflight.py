@@ -157,7 +157,7 @@ class InflightServer:
         return int((self.slot_rid >= 0).sum())
 
     # ----------------------------------------------------------- admission
-    def _plan_lookahead(self, limit: int) -> None:
+    def _plan_lookahead(self, limit: int, step: int) -> None:
         """Plan up to ``limit`` queued queries ahead of slot availability.
 
         Called right after a dispatch goes out: under async dispatch the
@@ -166,7 +166,9 @@ class InflightServer:
         """
         while self._queue and len(self._planned) < limit:
             rid, q_terms, t_enq = self._queue.popleft()
-            self._planned.append((rid, self.bengine.plan(q_terms), t_enq))
+            with self.obs.phase("plan", server="inflight", step=step):
+                plan = self.bengine.plan(q_terms)
+            self._planned.append((rid, plan, t_enq))
 
     def _admission_budget(self, plan: QueryPlan) -> int:
         b = np.asarray(self.budgeter.budgets(1, plans=[plan]), dtype=np.int64)
@@ -217,13 +219,11 @@ class InflightServer:
         self.slot_rid[lane] = -1
         self.slot_quanta[lane] = 0
         self._prev_postings[lane] = 0
-        if self.obs.enabled:
-            self.obs.count("parks", server="inflight")
 
-    def _admit_vacant(self) -> None:
+    def _admit_vacant(self, step: int) -> None:
         for lane in np.nonzero(self.slot_rid < 0)[0]:
             if not self._planned:
-                self._plan_lookahead(1)
+                self._plan_lookahead(1, step)
                 if not self._planned:
                     break
             rid, plan, t_enq = self._planned.popleft()
@@ -231,65 +231,84 @@ class InflightServer:
 
     # ------------------------------------------------------------ stepping
     def step(self) -> list[ServedQuery]:
-        """One quantum: admit, dispatch, fetch, retire exited slots."""
-        self._admit_vacant()
+        """One quantum: admit, dispatch, fetch, retire exited slots.
+
+        Each part runs inside an ``obs.phase`` (``admit``, ``launch`` with
+        ``upload`` and ``dispatch`` in it, ``plan_ahead``, ``fetch``,
+        ``retire``; ``plan`` around every plan), numbered by the step's
+        ``steps_run`` so that a query's ``dispatch`` span joins them.
+        """
+        obs = self.obs
+        n = self.steps_run + 1
+        with obs.phase("admit", server="inflight", step=n):
+            self._admit_vacant(n)
         if self.active == 0:
             return []
-        self.buffers.swap()  # pending lane writes go live
-        front = self.buffers.front
         eng = self.engine
 
-        prof = self.obs.profiler if self.obs.enabled else None
-        if prof is not None:
-            cache0 = jit_cache_size(batched_traverse_resume)
-        t0 = self.clock()
-        blk, rest, order, bounds, budget, maxr = front.device_arrays()
-        out = batched_traverse_resume(
-            eng.dix,
-            blk,
-            rest,
-            order,
-            bounds,
-            budget,
-            maxr,
-            _carry_to_device(self.carry),
-            s_pad=eng.s_pad,
-            k=eng.k,
-            quantum=self.quantum,
-            impl=eng.impl,
-            docs_format=eng.docs_format,
-        )
+        prof = obs.profiler if obs.enabled else None
+        with obs.phase("launch", server="inflight", step=n) as launch:
+            with obs.phase("upload", server="inflight", step=n):
+                self.buffers.swap()  # pending lane writes go live
+                front = self.buffers.front
+                if prof is not None:
+                    cache0 = jit_cache_size(batched_traverse_resume)
+                t0 = self.clock()
+                blk, rest, order, bounds, budget, maxr = front.device_arrays()
+                carry = _carry_to_device(self.carry)
+            with obs.phase("dispatch", server="inflight", step=n):
+                out = batched_traverse_resume(
+                    eng.dix,
+                    blk,
+                    rest,
+                    order,
+                    bounds,
+                    budget,
+                    maxr,
+                    carry,
+                    s_pad=eng.s_pad,
+                    k=eng.k,
+                    quantum=self.quantum,
+                    impl=eng.impl,
+                    docs_format=eng.docs_format,
+                )
         self.compiled_shapes.add((self.n_slots, front.width))
-        self.steps_run += 1
-        if prof is not None:
-            t_disp1 = self.clock()
+        self.steps_run = n
 
         # Async dispatch: the device is scoring; overlap host-side planning
         # for the admissions this step's exits will make room for.
-        self._plan_lookahead(self.n_slots)
+        with obs.phase("plan_ahead", server="inflight", step=n) as ahead:
+            self._plan_lookahead(self.n_slots, n)
 
-        if prof is not None:
-            # Timing-only sync: splits the device wait out of the carry
-            # fetch below. Results are untouched.
-            t_plan1 = self.clock()
-            jax.block_until_ready(out)  # analysis: allow[HOSTSYNC]
-            t_dev1 = self.clock()
-        self.carry = _carry_to_host(out)  # blocks until the quantum lands
-        t1 = self.clock()
+        with obs.phase("fetch", server="inflight", step=n) as fetch:
+            if prof is not None:
+                # Timing-only sync: splits the device wait out of the carry
+                # fetch below. Results are untouched.
+                jax.block_until_ready(out)  # analysis: allow[HOSTSYNC]
+                t_dev1 = obs.clock()
+            self.carry = _carry_to_host(out)  # blocks until the quantum lands
+            t1 = self.clock()
         step_ms = (t1 - t0) * 1e3
-        if prof is not None:
-            prof.record_dispatch(
-                "inflight",
-                (self.n_slots, front.width),
-                cache_before=cache0,
-                cache_after=jit_cache_size(batched_traverse_resume),
-                plan_ms=(t_plan1 - t_disp1) * 1e3,
-                dispatch_ms=(t_disp1 - t0) * 1e3,
-                device_ms=(t_dev1 - t_plan1) * 1e3,
-                transfer_ms=(t1 - t_dev1) * 1e3,
-            )
-            prof.record_hbm_once("inflight", eng.dix._asdict())
 
+        with obs.phase("retire", server="inflight", step=n):
+            if prof is not None:
+                # The host split reads the phase boundaries: one set of
+                # clock readings a step, plus the fence's.
+                prof.record_dispatch(
+                    "inflight",
+                    (self.n_slots, front.width),
+                    cache_before=cache0,
+                    cache_after=jit_cache_size(batched_traverse_resume),
+                    plan_ms=ahead.ms,
+                    dispatch_ms=launch.ms,
+                    device_ms=(t_dev1 - fetch.t0) * 1e3,
+                    transfer_ms=(fetch.t1 - t_dev1) * 1e3,
+                )
+                prof.record_hbm_once("inflight", eng.dix._asdict())
+            return self._retire(t0, t1, step_ms)
+
+    def _retire(self, t0: float, t1: float, step_ms: float) -> list[ServedQuery]:
+        """Count the quantum's work, answer and park exited lanes, feed the budgeter."""
         active = self.slot_rid >= 0
         postings = np.asarray(self.carry.state.postings, dtype=np.int64)
         delta = int((postings[active] - self._prev_postings[active]).sum())
@@ -299,18 +318,17 @@ class InflightServer:
         obs = self.obs
         if obs.enabled:
             obs.observe("step_ms", step_ms, server="inflight")
-            obs.observe("active_lanes", int(active.sum()), server="inflight")
             obs.gauge(
                 "slot_occupancy", float(active.sum()) / self.n_slots,
                 server="inflight",
             )
             obs.gauge("queue_depth", float(self.pending), server="inflight")
             for lane in np.nonzero(active)[0]:
-                # Device-step attribution: the quantum's host-observed wall
-                # time, shared by every lane riding this dispatch.
+                # The quantum's host-observed wall time, shared by every
+                # lane riding this dispatch.
                 obs.trace_span(
                     int(self.slot_rid[lane]), "dispatch", t0, t1,
-                    device_ms=round(step_ms, 4), step=self.steps_run,
+                    step_ms=round(step_ms, 4), step=self.steps_run,
                 )
 
         served: list[ServedQuery] = []

@@ -412,3 +412,74 @@ def test_budgeter_latencies_override_device_time():
         elapsed_ms=10.0, total_postings=1000, n=2, latencies_ms=[]
     )
     assert rate_only.policy.alpha == a0  # empty list: rate EWMA only
+
+
+@pytest.mark.parametrize("cap", [INT32_MAX, 100])
+def test_phases_on_and_off_serve_bitwise_equal(cap):
+    """An annotating, enabled, profiled handle changes no result bit."""
+    import contextlib
+
+    from repro.obs import NOOP, Instrumentation
+
+    eng, queries = _small_setup(seed=21, n_ranges=6, n_queries=12)
+    opened = []
+
+    def annotate(name, **args):
+        opened.append(name)
+        return contextlib.nullcontext()
+
+    def serve(obs):
+        srv = InflightServer(
+            BatchEngine(eng, BucketSpec(max_batch=4)),
+            FixedBudgeter(cap=cap),
+            n_slots=4,
+            quantum=2,
+            obs=obs,
+        )
+        return srv, sorted(srv.replay(queries), key=lambda s: s.rid)
+
+    obs = Instrumentation.make(sample_rate=1.0, profile=True, annotate=annotate)
+    srv_on, on = serve(obs)
+    srv_off, off = serve(NOOP)
+    assert opened and srv_on.steps_run == srv_off.steps_run
+    assert [s.rid for s in on] == [s.rid for s in off] == list(range(len(queries)))
+    for a, b in zip(on, off):
+        assert a.quanta == b.quanta
+        ra, rb = a.result, b.result
+        assert np.asarray(ra.doc_ids).tobytes() == np.asarray(rb.doc_ids).tobytes()
+        assert np.asarray(ra.scores).tobytes() == np.asarray(rb.scores).tobytes()
+        assert (ra.exit_safe, ra.exit_budget, ra.ranges_processed) == (
+            rb.exit_safe, rb.exit_budget, rb.ranges_processed
+        )
+        assert (ra.postings, ra.blocks) == (rb.postings, rb.blocks)
+
+
+@pytest.mark.parametrize(
+    "impl,docs_format", [("xla", "int32"), ("xla", "packed"), ("pallas", "int32")]
+)
+def test_traversal_ops_carry_their_layer_scopes(impl, docs_format):
+    """The compiled traversal names its gather, accumulate and top-k work."""
+    import re
+
+    corpus = make_corpus(n_docs=900, n_terms=700, n_topics=4, mean_doc_len=50, seed=3)
+    idx = build_index(corpus, n_ranges=6, strategy="clustered")
+    eng = Engine(idx, k=5, impl=impl, docs_format=docs_format)
+    plan = eng.plan(make_query_log(corpus, n_queries=2, seed=4).terms[0])
+    table = SlotTable(4, idx.n_ranges, plan.blk_tab.shape[1])
+    table.write_lane(0, plan)
+    text = (
+        batched_traverse_resume.lower(
+            eng.dix,
+            *table.device_arrays(),
+            _to_device(batched_init_carry(4, eng.k)),
+            s_pad=eng.s_pad,
+            k=eng.k,
+            quantum=2,
+            impl=impl,
+            docs_format=docs_format,
+        )
+        .compile()
+        .as_text()
+    )
+    parts = {p for name in re.findall(r'op_name="([^"]*)"', text) for p in name.split("/")}
+    assert {"gather", "accumulate", "topk"} <= parts

@@ -791,3 +791,135 @@ def test_watch_cli(tmp_path, capsys):
     assert "served_queries" in out and "latency_ms" in out
     assert "skew" in out  # the alert tail rendered
     assert main(["watch", str(tmp_path / "missing.json"), "--once"]) == 1
+
+
+# --------------------------------------------------------------------------
+# phases: named parts of a server step on the profiler trace's clock
+# --------------------------------------------------------------------------
+
+STEP_PHASES = ["admit", "launch", "plan_ahead", "fetch", "retire"]
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs opens and closes."""
+
+    def __init__(self, log, name, **args):
+        self.log, self.name, self.args = log, name, args
+
+    def __enter__(self):
+        self.log.append(("open", self.name, self.args))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name, self.args))
+
+
+def _phase_tree(log):
+    """Top-level phases in order, each with the phases nested in it."""
+    top, stack = [], []
+    for kind, name, args in log:
+        if kind == "open":
+            node = (name, args, [])
+            (stack[-1][2] if stack else top).append(node)
+            stack.append(node)
+        else:
+            assert stack and stack[-1][0] == name, "phases close innermost first"
+            stack.pop()
+    assert not stack
+    return top
+
+
+def test_noop_phase_is_one_shared_null_context():
+    from repro.obs import NoopInstrumentation
+
+    first = NOOP.phase("admit", server="inflight", step=1)
+    assert NOOP.phase("fetch", step=2) is first
+    assert NoopInstrumentation().phase("retire") is first
+    with NOOP.phase("plan", step=3):
+        pass
+    assert NOOP.snapshot() == {}
+
+
+def test_enabled_handle_records_phase_ms_per_phase():
+    obs = Instrumentation(clock=FakeClock(dt=0.5))
+    with obs.phase("launch", server="inflight", step=1) as launch:
+        with obs.phase("upload", server="inflight", step=1):
+            pass
+    with obs.phase("fetch", server="inflight", step=1):
+        pass
+    h = obs.metrics.histogram("phase_ms")
+    for phase in ("launch", "upload", "fetch"):
+        assert h.count(server="inflight", phase=phase) == 1
+    # Every clock reading advances 0.5 s: upload spans one tick, launch three.
+    assert h.mean(server="inflight", phase="upload") == pytest.approx(500.0)
+    assert launch.ms == pytest.approx(1500.0)
+    assert "# HELP phase_ms" in prometheus_text(obs.metrics)
+    assert obs.metrics.missing_help() == []
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_annotator_sees_every_step_phase_in_order(enabled):
+    from repro.obs import NoopInstrumentation
+
+    eng, queries = _small_setup(seed=6, n_ranges=6)
+    log = []
+
+    def annotate(name, **args):
+        return _FakeAnnotation(log, name, **args)
+
+    obs = (
+        Instrumentation.make(sample_rate=1.0, profile=True, annotate=annotate)
+        if enabled
+        else NoopInstrumentation(annotate=annotate)
+    )
+    srv = InflightServer(
+        BatchEngine(eng, BucketSpec(max_batch=4)),
+        SlaBudgeter(sla_ms=float("inf")),
+        n_slots=4,
+        quantum=2,
+        obs=obs,
+    )
+    served = srv.replay(queries)
+    assert len(served) == len(queries)
+
+    tree = _phase_tree(log)
+    assert all(name.startswith("repro.") for _, name, _ in log)
+    steps = [tree[i : i + 5] for i in range(0, len(tree), 5)]
+    assert len(steps) == srv.steps_run
+    n_plans = 0
+    for n, phases in enumerate(steps, start=1):
+        assert [name[len("repro."):] for name, _, _ in phases] == STEP_PHASES
+        for name, args, inner in phases:
+            assert args == {"server": "inflight", "step": n}
+            kids = [c[0] for c in inner]
+            if name == "repro.launch":
+                assert kids == ["repro.upload", "repro.dispatch"]
+            elif name in ("repro.admit", "repro.plan_ahead"):
+                assert set(kids) <= {"repro.plan"}
+                assert all(c[1]["step"] == n for c in inner)
+                n_plans += len(kids)
+            else:
+                assert kids == []
+    assert n_plans == len(queries)  # every plan is named, inside one of the two
+    if enabled:
+        h = obs.metrics.histogram("phase_ms")
+        assert h.count(server="inflight", phase="plan") == len(queries)
+        for phase in STEP_PHASES:
+            assert h.count(server="inflight", phase=phase) == srv.steps_run
+        snap = obs.profiler.snapshot()["inflight"]
+        assert snap["dispatches"] == srv.steps_run
+
+
+def test_obs_imports_no_jax():
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    code = "import sys, repro.obs; print(sorted(m for m in sys.modules if m.split('.')[0] == 'jax'))"
+    env = dict(os.environ, PYTHONPATH=str(src))
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True, check=True
+    )
+    assert out.stdout.strip() == "[]"
