@@ -99,7 +99,7 @@ class AnytimeIRArch(Arch):
         del variant, ctx
         m = "model"
         return dir_mod.ShardedIndexArrays(
-            docs=P(m, None), impacts=P(m, None), blk_start=P(m, None),
+            blk_docs=P(m, None, None), blk_imps=P(m, None, None),
             blk_len=P(m, None), blk_maximp=P(m, None), range_starts=P(m, None),
             doc_base=P(m), s_pad=cfg["s_pad"], k=cfg["k"],
         )

@@ -12,8 +12,10 @@ Host-side structure (numpy, built offline):
     BoundSum and by safe early termination;
   * the cluster map (range_ends) — the paper's C vector.
 
-Device-side mirror (`DeviceIndex`) holds flat jnp arrays; traversal code in
-range_daat.py / saat.py consumes it. TPU adaptation notes in DESIGN.md §2.
+Device-side mirror (`DeviceIndex`) holds the postings block-major, one
+BLOCK-lane row per block (``block_rows``), and the block metadata as flat
+jnp arrays; traversal code in range_daat.py consumes it. TPU adaptation
+notes in DESIGN.md §1-§2.
 """
 
 from __future__ import annotations
@@ -60,6 +62,7 @@ __all__ = [
     "PackedPostings",
     "apply_delta",
     "balance_range_shards",
+    "block_rows",
     "build_index",
     "build_index_cached",
     "device_bytes_report",
@@ -93,9 +96,12 @@ def device_bytes_report(
     the same report straight from manifest metadata without loading any
     array (``python -m repro.index_io inspect``).
 
+    Postings are uploaded block-major (``block_rows``): ``docs`` and
+    ``impacts`` count every one of a block's BLOCK lanes, real or padding,
+    so their bytes scale with ``n_blocks``, not ``nnz`` (DESIGN.md §8).
     With ``docs_format="packed"`` the ``docs`` entry covers the bit-packed
     delta word stream plus its per-block (word_start, width, first_doc)
-    directory (DESIGN.md §12) — the int32 docid array is not uploaded.
+    directory (DESIGN.md §12) — no docid rows are uploaded.
     """
     if impact_dtype not in ("int32", "int8"):
         raise ValueError(f"impact_dtype {impact_dtype!r} not in ('int32', 'int8')")
@@ -107,11 +113,10 @@ def device_bytes_report(
         # (word_start | width_code << PACK_DIR_BITS) and the first docid.
         docs_bytes = n_pack_words * 4 + 2 * n_blocks * 4
     else:
-        docs_bytes = nnz * 4
+        docs_bytes = n_blocks * BLOCK * 4
     out = {
         "docs": docs_bytes,
-        "impacts": nnz * imp_itemsize,
-        "blk_start": n_blocks * 4,
+        "impacts": n_blocks * BLOCK * imp_itemsize,
         "blk_len": n_blocks * 4,
         "blk_maximp": n_blocks * 4,
         "bounds_dense": n_terms * n_ranges * 4,
@@ -162,6 +167,36 @@ def _segment_arange(lens: np.ndarray) -> np.ndarray:
     total = int(lens.sum())
     ends = np.cumsum(lens)
     return np.arange(total, dtype=np.int64) - np.repeat(ends - lens, lens)
+
+
+def block_rows(
+    values: np.ndarray,
+    blk_start: np.ndarray,
+    blk_len: np.ndarray,
+    chunk: int = 1 << 15,
+) -> np.ndarray:
+    """[NB, BLOCK] block-major copy of a flat per-posting array.
+
+    Row ``b`` holds ``values[blk_start[b] : blk_start[b] + blk_len[b]]`` in
+    its first ``blk_len[b]`` lanes and 0 in the rest, which the scorer's
+    lane mask (``lane < blk_len``) drops. The device gathers whole rows by
+    block id (DESIGN.md §1, §8). Built ``chunk`` blocks at a time, so the
+    int64 offsets held at once cover at most ``chunk * BLOCK`` postings.
+    """
+    values = np.asarray(values)
+    blk_start = np.asarray(blk_start, np.int64)
+    blk_len = np.asarray(blk_len, np.int64)
+    nb = int(blk_start.shape[0])
+    if nb and int(blk_len.max(initial=0)) > BLOCK:
+        raise ValueError(f"block length exceeds BLOCK={BLOCK}")
+    rows = np.zeros((nb, BLOCK), values.dtype)
+    flat = rows.reshape(-1)
+    for b0 in range(0, nb, chunk):
+        lens = blk_len[b0 : b0 + chunk]
+        lane = _segment_arange(lens)
+        seg = np.repeat(np.arange(b0, b0 + lens.shape[0], dtype=np.int64), lens)
+        flat[seg * BLOCK + lane] = values[blk_start[seg] + lane]
+    return rows
 
 
 def pack_docs(
@@ -345,6 +380,10 @@ class ClusteredIndex:
         return self.arrangement.range_starts
 
     @property
+    def range_sizes(self) -> np.ndarray:
+        return self.arrangement.range_sizes
+
+    @property
     def max_range_size(self) -> int:
         return int(self.arrangement.range_sizes.max())
 
@@ -369,11 +408,12 @@ class ClusteredIndex:
 
         Mirrors exactly what ``range_daat.Engine`` uploads as its
         ``DeviceIndex`` — one entry per device array (all int32 except
-        ``impacts``, which is 1 B/posting under ``impact_dtype="int8"``,
-        DESIGN.md §8) plus ``postings`` (docs + impacts) and ``total``
-        aggregates; under ``docs_format="packed"`` the ``docs`` entry is
-        the packed word stream + directory (DESIGN.md §12). Tests assert
-        these equal the uploaded buffers' nbytes.
+        ``impacts``, which is 1 B per block lane under
+        ``impact_dtype="int8"``, DESIGN.md §8; ``docs`` and ``impacts`` are
+        the ``blk_docs`` and ``blk_imps`` rows) plus ``postings`` (docs +
+        impacts) and ``total`` aggregates; under ``docs_format="packed"``
+        the ``docs`` entry is the packed word stream + directory (DESIGN.md
+        §12). Tests assert these equal the uploaded buffers' nbytes.
         """
         n_pack_words = (
             self.packed_postings().n_words if docs_format == "packed" else 0

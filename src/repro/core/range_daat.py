@@ -25,7 +25,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bound_sum
-from repro.core.clustered_index import BLOCK, ClusteredIndex, pack_dir_entries
+from repro.core.clustered_index import (
+    BLOCK,
+    ClusteredIndex,
+    block_rows,
+    pack_dir_entries,
+    pack_docs,
+)
 from repro.kernels.range_scorer import ops as scorer_ops
 
 __all__ = [
@@ -51,6 +57,7 @@ __all__ = [
     "batched_traverse_resume",
     "topk_docs",
     "batched_topk_docs",
+    "device_index",
     "exit_reason",
     "exit_reasons",
 ]
@@ -65,8 +72,8 @@ def pack_impacts(impacts: np.ndarray, impact_dtype: str) -> np.ndarray:
 
     ``"int32"`` uploads impacts verbatim; ``"int8"`` stores the biased code
     ``impact - IMPACT_BIAS`` so 8-bit quantized impacts (range [1, 255])
-    fit a signed byte — 1 B/posting in HBM, widened back inside the scorer
-    gather (DESIGN.md §8). Requires every impact <= 2^8 - 1; the caller
+    fit a signed byte — 1 B per block lane in HBM, widened back inside the
+    scorer gather (DESIGN.md §8). Requires every impact <= 2^8 - 1; the caller
     (``Engine``) enforces this via the quantizer's bit width.
     """
     if impact_dtype == "int32":
@@ -77,19 +84,20 @@ def pack_impacts(impacts: np.ndarray, impact_dtype: str) -> np.ndarray:
 
 
 class DeviceIndex(NamedTuple):
-    """jnp mirror of the host index (flat arrays only — a valid pytree).
+    """jnp mirror of the host index (a valid pytree).
 
-    Under ``docs_format="packed"`` (DESIGN.md §12), ``docs`` shrinks to a
-    (1,)-placeholder (never gathered) and the three ``pack_*`` leaves carry
-    the bit-packed delta stream plus its per-block merged directory
-    (``pack_dir_entries``), parallel to ``blk_start``. They default to
-    None in the raw-int32 layout; None leaves vanish from the pytree, so
-    vmap/shard_map over either shape works unchanged.
+    Postings are block-major (DESIGN.md §1): row ``b`` of ``blk_docs`` and
+    ``blk_imps`` holds block ``b``'s postings in its first ``blk_len[b]``
+    lanes (``block_rows``), so the scorer gathers whole rows by block id.
+    Under ``docs_format="packed"`` (DESIGN.md §12), ``blk_docs`` is None
+    and the three ``pack_*`` leaves carry the bit-packed delta stream plus
+    its per-block merged directory (``pack_dir_entries``), parallel to
+    ``blk_len``. They are None in the raw-int32 layout; None leaves vanish
+    from the pytree, so vmap/shard_map over either shape works unchanged.
     """
 
-    docs: jnp.ndarray  # [nnz] int32 (packed: [1] placeholder)
-    impacts: jnp.ndarray  # [nnz] int32, or int8 biased by IMPACT_BIAS (§8)
-    blk_start: jnp.ndarray  # [NB] int32
+    blk_docs: jnp.ndarray | None  # [NB, BLOCK] int32 (packed: None)
+    blk_imps: jnp.ndarray  # [NB, BLOCK] int32, or int8 biased by IMPACT_BIAS (§8)
     blk_len: jnp.ndarray  # [NB] int32
     blk_maximp: jnp.ndarray  # [NB] int32
     bounds_dense: jnp.ndarray  # [V, R] int32
@@ -98,6 +106,46 @@ class DeviceIndex(NamedTuple):
     pack_words: jnp.ndarray | None = None  # [n_words] uint32 delta stream
     pack_dir: jnp.ndarray | None = None  # [NB] int32 merged (start | width)
     pack_first: jnp.ndarray | None = None  # [NB] int32 first docid per block
+
+
+def device_index(
+    index, impact_dtype: str = "int32", docs_format: str = "int32"
+) -> DeviceIndex:
+    """Host (numpy) ``DeviceIndex`` of a ``ClusteredIndex`` or ``IndexShard``.
+
+    The single source of the device layout: every engine uploads what this
+    returns (as is, or stacked shard-major). Postings go block-major
+    through ``block_rows``; int8 impacts keep their biased code, so a block
+    lane costs the same HBM bytes as a flat posting did (DESIGN.md §8).
+    """
+    if docs_format not in DOCS_FORMATS:
+        raise ValueError(f"docs_format {docs_format!r} not in {DOCS_FORMATS}")
+    geometry = (index.blk_start, index.blk_len)
+    pack = {}
+    blk_docs = None
+    if docs_format == "packed":
+        packed = (
+            index.packed_postings()
+            if isinstance(index, ClusteredIndex)
+            else pack_docs(index.docs, *geometry)
+        )
+        pack = dict(
+            pack_words=np.asarray(packed.words, np.uint32),
+            pack_dir=pack_dir_entries(packed),
+            pack_first=np.asarray(packed.blk_first, np.int32),
+        )
+    else:
+        blk_docs = block_rows(np.asarray(index.docs, np.int32), *geometry)
+    return DeviceIndex(
+        blk_docs=blk_docs,
+        blk_imps=block_rows(pack_impacts(index.impacts, impact_dtype), *geometry),
+        blk_len=np.asarray(index.blk_len, np.int32),
+        blk_maximp=np.asarray(index.blk_maximp, np.int32),
+        bounds_dense=np.asarray(index.bounds_dense, np.int32),
+        range_starts=np.asarray(index.range_starts, np.int32),
+        range_sizes=np.asarray(index.range_sizes, np.int32),
+        **pack,
+    )
 
 
 class TopKState(NamedTuple):
@@ -175,14 +223,13 @@ def score_range_step(
     """Score one range and merge its top-k into the running state.
 
     Its device work carries three named scopes, the layers a profiler trace
-    splits it by: ``gather`` (block metadata, the prune mask, postings),
-    ``accumulate`` (the scatter into the range accumulator) and ``topk``
-    (the range's top-k and the merge into the heap).
+    splits it by: ``gather`` (block metadata, the prune mask, the postings'
+    block rows), ``accumulate`` (the scatter into the range accumulator)
+    and ``topk`` (the range's top-k and the merge into the heap).
     """
     th = theta(state)
     with jax.named_scope("gather"):
         safe_ids = jnp.clip(blk_ids, 0).astype(jnp.int32)
-        starts = dix.blk_start[safe_ids]
         lens = dix.blk_len[safe_ids]
         maximp = dix.blk_maximp[safe_ids]
         keep = blk_ids >= 0
@@ -204,9 +251,9 @@ def score_range_step(
                 pack_firsts=dix.pack_first[safe_ids],
             )
     acc = scorer_ops.score_blocks(
-        dix.docs,
-        dix.impacts,
-        starts,
+        dix.blk_docs,
+        dix.blk_imps,
+        blk_ids,
         lens,
         keep,
         range_start,
@@ -623,7 +670,8 @@ class Engine:
     impacts in HBM, widened only inside the scorer gather (DESIGN.md §8).
     ``docs_format``: "int32" (default) or "packed" — bit-packed per-block
     docid deltas in HBM, decoded inside the scorer (DESIGN.md §12); bitwise
-    identical results by contract.
+    identical results by contract. Either way the postings live on the
+    device as one BLOCK-lane row per block (``device_index``).
     """
 
     def __init__(
@@ -653,35 +701,17 @@ class Engine:
                 f"got {index.quantizer.bits}"
             )
         self.impact_dtype = impact_dtype
-        if docs_format not in DOCS_FORMATS:
-            raise ValueError(f"docs_format {docs_format!r} not in {DOCS_FORMATS}")
         self.docs_format = docs_format
         self.s_pad = int(
             (index.max_range_size + BLOCK - 1) // BLOCK * BLOCK
         ) or BLOCK
-        if docs_format == "packed":
-            packed = index.packed_postings()
-            # The raw docid array stays on the host; the scorer never
-            # gathers it, so a (1,)-placeholder keeps the pytree shape.
-            docs_dev = jnp.zeros((1,), jnp.int32)
-            pack_dev = dict(
-                pack_words=jnp.asarray(packed.words, jnp.uint32),
-                pack_dir=jnp.asarray(pack_dir_entries(packed), jnp.int32),
-                pack_first=jnp.asarray(packed.blk_first, jnp.int32),
-            )
-        else:
-            docs_dev = jnp.asarray(index.docs, jnp.int32)
-            pack_dev = {}
-        self.dix = DeviceIndex(
-            docs=docs_dev,
-            impacts=jnp.asarray(pack_impacts(index.impacts, impact_dtype)),
-            blk_start=jnp.asarray(index.blk_start, jnp.int32),
-            blk_len=jnp.asarray(index.blk_len, jnp.int32),
-            blk_maximp=jnp.asarray(index.blk_maximp, jnp.int32),
-            bounds_dense=jnp.asarray(index.bounds_dense, jnp.int32),
-            range_starts=jnp.asarray(index.range_starts, jnp.int32),
-            range_sizes=jnp.asarray(index.arrangement.range_sizes, jnp.int32),
-            **pack_dev,
+        self.dix = jax.tree.map(
+            jnp.asarray, device_index(index, impact_dtype, docs_format)
+        )
+        # Percent of the uploaded block lanes that hold a posting: every
+        # gathered row pays for the rest as padding (DESIGN.md §8).
+        self.obs.gauge(
+            "index_block_fill", 100.0 * index.nnz / max(index.n_blocks * BLOCK, 1)
         )
 
     @classmethod

@@ -231,7 +231,7 @@ def _unpack_kernel(dir_ref, words_hbm, out_ref, win_ref, sem, *, b_tile: int):
 @functools.partial(jax.jit, static_argnames=("b_tile",))
 def unpack_locals_pallas(
     pack_words: jnp.ndarray,  # [n_words] uint32 packed delta stream
-    starts: jnp.ndarray,  # [B] block start offsets (-1 pad ok; validity only)
+    ids: jnp.ndarray,  # [B] block ids (-1 pad ok; validity only)
     lens: jnp.ndarray,  # [B] int32 block lengths
     pack_dir: jnp.ndarray,  # [B] int32 merged (word_start | width code)
     pack_firsts: jnp.ndarray,  # [B] absolute first docid per block
@@ -252,7 +252,7 @@ def unpack_locals_pallas(
     directory entries are clamped to a zero entry — word 0 width 0 — which
     is always in range).
     """
-    B = starts.shape[0]
+    B = ids.shape[0]
     # Pad to whole tiles plus one spare tile, so every 16-row window is in
     # bounds, and view the stream as [rows, 128] int32 rows.
     n_words = pack_words.shape[0]
@@ -268,7 +268,7 @@ def unpack_locals_pallas(
         jnp.maximum(pack_dir.astype(jnp.int32), 0),
         pack_firsts.astype(jnp.int32),
         lens.astype(jnp.int32),
-        (keep & (starts >= 0)).astype(jnp.int32),
+        (keep & (ids >= 0)).astype(jnp.int32),
         jnp.broadcast_to(range_start.astype(jnp.int32), (B,)),
     ])
     table = jnp.pad(table, ((0, 0), (0, bp - B)))  # pad blocks: zero entries

@@ -4,9 +4,10 @@
 selects the XLA scatter path (fast on CPU, the oracle) or the Pallas one-hot
 MXU kernel, which ``kernel.pallas_interpret`` interprets on CPU and compiles
 on TPU. ``docs_format``
-selects how block docids reach the scorer: ``"int32"`` gathers the raw docid
-array, ``"packed"`` decodes per-block bit-packed deltas in place
-(DESIGN.md §12) — the two are bitwise-identical by contract. Both paths
+selects how block docids reach the scorer: ``"int32"`` gathers the blocks'
+docid rows, ``"packed"`` decodes per-block bit-packed deltas in place
+(DESIGN.md §12) — the two are bitwise-identical by contract. Impacts are
+gathered as block rows in both. Both paths
 run their posting gathers under the named scope ``gather`` and their
 accumulation under ``accumulate``.
 """
@@ -34,9 +35,9 @@ __all__ = ["DOCS_FORMATS", "IMPACT_BIAS", "score_blocks"]
     jax.jit, static_argnames=("s_pad", "impl", "docs_format")
 )
 def score_blocks(
-    post_docs: jnp.ndarray,
-    post_imps: jnp.ndarray,
-    starts: jnp.ndarray,
+    blk_docs: jnp.ndarray | None,
+    blk_imps: jnp.ndarray,
+    ids: jnp.ndarray,
     lens: jnp.ndarray,
     keep: jnp.ndarray,
     range_start: jnp.ndarray,
@@ -50,11 +51,12 @@ def score_blocks(
 ) -> jnp.ndarray:
     """Accumulate surviving posting blocks into an int32 [s_pad] accumulator.
 
-    Under ``docs_format="packed"``, ``post_docs`` is ignored (pass any
-    placeholder) and the per-block merged directory (``pack_dir``, see
+    ``blk_docs`` and ``blk_imps`` are the index's ``[NB, BLOCK]`` block
+    rows and ``ids`` the ``[B]`` block ids to score (-1 pads). Under
+    ``docs_format="packed"``, ``blk_docs`` is ignored (pass None) and the
+    per-block merged directory (``pack_dir``, see
     ``core.clustered_index.pack_dir_entries``) and first-docid column plus
-    the shared ``pack_words`` stream are required; impacts stay
-    offset-addressed via ``starts`` in both formats.
+    the shared ``pack_words`` stream are required.
     """
     if docs_format not in DOCS_FORMATS:
         raise ValueError(f"docs_format {docs_format!r} not in {DOCS_FORMATS}")
@@ -65,26 +67,26 @@ def score_blocks(
     if impl == "xla":
         if docs_format == "packed":
             return ref.score_blocks_packed_ref(
-                pack_words, post_imps, starts, lens,
+                pack_words, blk_imps, ids, lens,
                 pack_dir, pack_firsts, keep, range_start, s_pad,
             )
         return ref.score_blocks_ref(
-            post_docs, post_imps, starts, lens, keep, range_start, s_pad
+            blk_docs, blk_imps, ids, lens, keep, range_start, s_pad
         )
     if impl == "pallas":
         if docs_format == "packed":
             with jax.named_scope("gather"):
                 local = unpack_locals_pallas(
-                    pack_words, starts, lens,
+                    pack_words, ids, lens,
                     pack_dir, pack_firsts, keep, range_start,
                 )
-                valid = ref._lane_valid(starts, lens, keep)
-                v = ref.gather_block_impacts(post_imps, starts)
+                valid = ref._lane_valid(ids, lens, keep)
+                v = ref.gather_block_impacts(blk_imps, ids)
                 vals = jnp.where(valid, v, 0).astype(jnp.int32).reshape(local.shape)
         else:
             with jax.named_scope("gather"):
                 local, vals = ref.gather_block_postings(
-                    post_docs, post_imps, starts, lens, keep, range_start
+                    blk_docs, blk_imps, ids, lens, keep, range_start
                 )
         with jax.named_scope("accumulate"):
             return scatter_accumulate_pallas(local, vals, s_pad=s_pad)

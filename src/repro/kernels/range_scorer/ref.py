@@ -2,6 +2,9 @@
 
 Scores one document range: gathers the postings of the query's surviving
 blocks and scatter-adds quantized impacts into a range-local accumulator.
+Postings are stored block-major (``core.clustered_index.block_rows``): one
+``[BLOCK]`` row per block, so a block's postings are one row gather by
+block id, the form the TPU reads at HBM speed.
 This is the semantic reference the Pallas kernel must match exactly
 (integer impacts; float32 accumulation is exact below 2^24). The posting
 gathers run under the named scope ``gather`` and the scatter-add under
@@ -28,6 +31,7 @@ __all__ = [
     "IMPACT_BIAS",
     "gather_block_impacts",
     "gather_block_postings",
+    "gather_block_rows",
     "gather_block_postings_packed",
     "score_blocks_packed_ref",
     "score_blocks_ref",
@@ -36,38 +40,40 @@ __all__ = [
 
 
 def _lane_valid(
-    starts: jnp.ndarray, lens: jnp.ndarray, keep: jnp.ndarray
+    ids: jnp.ndarray, lens: jnp.ndarray, keep: jnp.ndarray
 ) -> jnp.ndarray:
     """[B, BLOCK] lane validity shared by both docid formats."""
     lane = jnp.arange(BLOCK, dtype=jnp.int32)
-    return (lane[None, :] < lens[:, None]) & keep[:, None] & (starts >= 0)[:, None]
+    return (lane[None, :] < lens[:, None]) & keep[:, None] & (ids >= 0)[:, None]
+
+
+def gather_block_rows(rows: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
+    """[B, BLOCK] rows of a block-major array by block id (-1 pad reads row 0)."""
+    return rows[jnp.clip(ids, 0).astype(jnp.int32)]
 
 
 def gather_block_impacts(
-    post_imps: jnp.ndarray,  # [nnz] int32 or biased int8 impacts
-    starts: jnp.ndarray,  # [B] block start offsets (-1 pad ok)
+    blk_imps: jnp.ndarray,  # [NB, BLOCK] int32 or biased int8 impacts
+    ids: jnp.ndarray,  # [B] block ids (-1 pad ok)
 ) -> jnp.ndarray:
-    """Gather [B, BLOCK] widened impact values by posting offset.
+    """Gather [B, BLOCK] widened impact values by block id.
 
-    Impacts stay offset-addressed in every docs format — packed blocks
-    replace only the docid stream, so ``blk_start`` still indexes impacts.
+    Impacts are block rows in every docs format — packed blocks replace
+    only the docid stream.
     """
-    lane = jnp.arange(BLOCK, dtype=jnp.int32)
-    offs = starts.astype(jnp.int32)[:, None] + lane[None, :]  # [B, BLOCK]
-    nnz = post_imps.shape[0]
-    v = post_imps[jnp.clip(offs, 0, nnz - 1)]
-    if post_imps.dtype == jnp.int8:
+    v = gather_block_rows(blk_imps, ids)
+    if blk_imps.dtype == jnp.int8:
         # Native int8 impact storage: codes are biased by IMPACT_BIAS so the
-        # widen is the only place the true impact is reconstructed — postings
-        # stay 1 B/posting in HBM (DESIGN.md §8).
+        # widen is the only place the true impact is reconstructed — impacts
+        # stay 1 B per block lane in HBM (DESIGN.md §8).
         v = v.astype(jnp.int32) + IMPACT_BIAS
     return v
 
 
 def gather_block_postings(
-    post_docs: jnp.ndarray,  # [nnz] int32 docids (new ids)
-    post_imps: jnp.ndarray,  # [nnz] int32 impacts
-    starts: jnp.ndarray,  # [B] int32/int64 block start offsets (-1 pad ok)
+    blk_docs: jnp.ndarray,  # [NB, BLOCK] int32 docids (new ids)
+    blk_imps: jnp.ndarray,  # [NB, BLOCK] int32 or biased int8 impacts
+    ids: jnp.ndarray,  # [B] int32/int64 block ids (-1 pad ok)
     lens: jnp.ndarray,  # [B] int32 block lengths
     keep: jnp.ndarray,  # [B] bool survives pruning
     range_start: jnp.ndarray,  # scalar int32 first new-docid of the range
@@ -77,13 +83,10 @@ def gather_block_postings(
     Invalid lanes get local_id = -1 and value = 0 so any downstream
     accumulator drops them.
     """
-    B = starts.shape[0]
-    lane = jnp.arange(BLOCK, dtype=jnp.int32)
-    offs = starts.astype(jnp.int32)[:, None] + lane[None, :]  # [B, BLOCK]
-    valid = _lane_valid(starts, lens, keep)
-    nnz = post_docs.shape[0]
-    d = post_docs[jnp.clip(offs, 0, nnz - 1)]
-    v = gather_block_impacts(post_imps, starts)
+    B = ids.shape[0]
+    valid = _lane_valid(ids, lens, keep)
+    d = gather_block_rows(blk_docs, ids)
+    v = gather_block_impacts(blk_imps, ids)
     local = jnp.where(valid, d - range_start, -1).astype(jnp.int32)
     vals = jnp.where(valid, v, 0).astype(jnp.int32)
     return local.reshape(B * BLOCK), vals.reshape(B * BLOCK)
@@ -104,8 +107,8 @@ def unpack_dir(pack_dir: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 def gather_block_postings_packed(
     pack_words: jnp.ndarray,  # [n_words] uint32 packed delta stream
-    post_imps: jnp.ndarray,  # [nnz] int32 or biased int8 impacts
-    starts: jnp.ndarray,  # [B] block start offsets into impacts (-1 pad ok)
+    blk_imps: jnp.ndarray,  # [NB, BLOCK] int32 or biased int8 impacts
+    ids: jnp.ndarray,  # [B] block ids (-1 pad ok)
     lens: jnp.ndarray,  # [B] int32 block lengths
     pack_dir: jnp.ndarray,  # [B] int32 merged (word_start | width code)
     pack_firsts: jnp.ndarray,  # [B] int32 absolute first docid per block
@@ -121,9 +124,9 @@ def gather_block_postings_packed(
     *before* the cumsum so tail garbage can never leak into valid lanes.
     Returns the identical (local_id, value) contract, bitwise.
     """
-    B = starts.shape[0]
+    B = ids.shape[0]
     lane = jnp.arange(BLOCK, dtype=jnp.int32)
-    valid = _lane_valid(starts, lens, keep)
+    valid = _lane_valid(ids, lens, keep)
     pack_starts, widths = unpack_dir(pack_dir)
     w = widths[:, None]  # [B, 1]
     bit = lane[None, :] * w  # [B, BLOCK]
@@ -143,16 +146,16 @@ def gather_block_postings_packed(
     in_len = lane[None, :] < lens[:, None]
     delta = jnp.where(in_len, delta, jnp.uint32(0)).astype(jnp.int32)
     d = pack_firsts.astype(jnp.int32)[:, None] + jnp.cumsum(delta, axis=1)
-    v = gather_block_impacts(post_imps, starts)
+    v = gather_block_impacts(blk_imps, ids)
     local = jnp.where(valid, d - range_start, -1).astype(jnp.int32)
     vals = jnp.where(valid, v, 0).astype(jnp.int32)
     return local.reshape(B * BLOCK), vals.reshape(B * BLOCK)
 
 
 def score_blocks_ref(
-    post_docs: jnp.ndarray,
-    post_imps: jnp.ndarray,
-    starts: jnp.ndarray,
+    blk_docs: jnp.ndarray,
+    blk_imps: jnp.ndarray,
+    ids: jnp.ndarray,
     lens: jnp.ndarray,
     keep: jnp.ndarray,
     range_start: jnp.ndarray,
@@ -161,7 +164,7 @@ def score_blocks_ref(
     """Accumulate surviving blocks into an int32 accumulator of size s_pad."""
     with jax.named_scope("gather"):
         local, vals = gather_block_postings(
-            post_docs, post_imps, starts, lens, keep, range_start
+            blk_docs, blk_imps, ids, lens, keep, range_start
         )
     with jax.named_scope("accumulate"):
         # local == -1 -> clamp to s_pad and drop via mode="drop".
@@ -172,8 +175,8 @@ def score_blocks_ref(
 
 def score_blocks_packed_ref(
     pack_words: jnp.ndarray,
-    post_imps: jnp.ndarray,
-    starts: jnp.ndarray,
+    blk_imps: jnp.ndarray,
+    ids: jnp.ndarray,
     lens: jnp.ndarray,
     pack_dir: jnp.ndarray,
     pack_firsts: jnp.ndarray,
@@ -184,7 +187,7 @@ def score_blocks_packed_ref(
     """Packed-format twin of :func:`score_blocks_ref` (same accumulator)."""
     with jax.named_scope("gather"):
         local, vals = gather_block_postings_packed(
-            pack_words, post_imps, starts, lens,
+            pack_words, blk_imps, ids, lens,
             pack_dir, pack_firsts, keep, range_start,
         )
     with jax.named_scope("accumulate"):

@@ -17,6 +17,8 @@ METRIC_HELP: dict[str, str] = {
     # ------------------------------------------------------- engine / batch
     "engine_queries": "Queries traversed by the host Engine, by exit reason.",
     "engine_postings": "Postings scored per host Engine traversal.",
+    "index_block_fill": "Percent of the uploaded block lanes holding a "
+    "posting: 100 x nnz / (blocks x 128).",
     "batch_engine_chunk_lanes": "Live lanes per padded BatchEngine chunk.",
     "batch_engine_queries": "Queries served by BatchEngine, by exit reason.",
     # ------------------------------------------------------------ budgeter
@@ -40,6 +42,8 @@ METRIC_HELP: dict[str, str] = {
     "latency_ms": "End-to-end query latency (submit to serve), by server.",
     "quanta": "Resume quanta a query lived through before completing.",
     "phase_ms": "Wall-clock per phase of a server step, by server and phase.",
+    "gather_fill": "Percent of the block lanes a dispatch gathered that held "
+    "a scored posting: 100 x postings / (slots x quantum x width x 128).",
     # ------------------------------------------------------------- sharded
     "sharded_queries": "Queries served through the sharded broker.",
     "shard_exits": "Per-shard exit reasons across sharded queries.",

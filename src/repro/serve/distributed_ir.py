@@ -32,7 +32,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import range_daat
-from repro.core.clustered_index import build_index
+from repro.core.clustered_index import BLOCK, build_index
 from repro.core.range_daat import DeviceIndex
 from repro.data.synth import Corpus
 from repro.distributed.sharding import ShardCtx
@@ -43,18 +43,21 @@ __all__ = ["ShardedIndexArrays", "build_sharded_index", "sharded_anytime_query",
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=(
-        "docs", "impacts", "blk_start", "blk_len", "blk_maximp",
+        "blk_docs", "blk_imps", "blk_len", "blk_maximp",
         "range_starts", "doc_base",
     ),
     meta_fields=("s_pad", "k"),
 )
 @dataclasses.dataclass(frozen=True)
 class ShardedIndexArrays:
-    """Shard-major device arrays (all leading dim = M shards)."""
+    """Shard-major device arrays (all leading dim = M shards).
 
-    docs: jnp.ndarray  # [M, NNZ] int32 local docids
-    impacts: jnp.ndarray  # [M, NNZ] int32
-    blk_start: jnp.ndarray  # [M, NB] int32
+    Postings are block rows (``range_daat.DeviceIndex``): each shard's NB
+    blocks, BLOCK lanes each, padded to the largest shard's NB.
+    """
+
+    blk_docs: jnp.ndarray  # [M, NB, BLOCK] int32 local docids
+    blk_imps: jnp.ndarray  # [M, NB, BLOCK] int32
     blk_len: jnp.ndarray  # [M, NB] int32
     blk_maximp: jnp.ndarray  # [M, NB] int32
     range_starts: jnp.ndarray  # [M, R_loc] int32 (local docid space)
@@ -112,11 +115,11 @@ def build_sharded_index(
         engines.append(Engine(idx, k=10))
         del remap
 
-    # Pad per-shard arrays to common sizes and stack shard-major.
-    def stack(get, pad_val=0, dtype=np.int32):
-        arrs = [np.asarray(get(e.index), dtype=dtype) for e in engines]
+    # Pad each engine's device leaves to common sizes and stack shard-major.
+    def stack(get):
+        arrs = [np.asarray(get(e.dix)) for e in engines]
         width = max(a.shape[0] for a in arrs)
-        out = np.full((n_shards, width), pad_val, dtype=dtype)
+        out = np.zeros((n_shards, width) + arrs[0].shape[1:], arrs[0].dtype)
         for m, a in enumerate(arrs):
             out[m, : a.shape[0]] = a
         return jnp.asarray(out)
@@ -128,12 +131,11 @@ def build_sharded_index(
     doc_base[1:] = np.cumsum(sizes)[:-1]
 
     arrays = ShardedIndexArrays(
-        docs=stack(lambda i: i.docs),
-        impacts=stack(lambda i: i.impacts),
-        blk_start=stack(lambda i: i.blk_start),
-        blk_len=stack(lambda i: i.blk_len),
-        blk_maximp=stack(lambda i: i.blk_maximp),
-        range_starts=stack(lambda i: i.range_starts),
+        blk_docs=stack(lambda d: d.blk_docs),
+        blk_imps=stack(lambda d: d.blk_imps),
+        blk_len=stack(lambda d: d.blk_len),
+        blk_maximp=stack(lambda d: d.blk_maximp),
+        range_starts=stack(lambda d: d.range_starts),
         doc_base=jnp.asarray(doc_base),
         s_pad=s_pad,
         k=10,
@@ -174,12 +176,13 @@ def sharded_query_specs(
 
     ``impact_dtype=jnp.int8`` stores quantized impacts at their native
     8-bit width (the paper's own quantization level) — §Perf cell C.
+    The ``nnz_loc`` postings of a shard live in its ``nb_loc`` block rows.
     """
+    del nnz_loc  # carried by nb_loc rows of BLOCK lanes
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     arrays = ShardedIndexArrays(
-        docs=i32(n_shards, nnz_loc),
-        impacts=jax.ShapeDtypeStruct((n_shards, nnz_loc), impact_dtype),
-        blk_start=i32(n_shards, nb_loc),
+        blk_docs=i32(n_shards, nb_loc, BLOCK),
+        blk_imps=jax.ShapeDtypeStruct((n_shards, nb_loc, BLOCK), impact_dtype),
         blk_len=i32(n_shards, nb_loc),
         blk_maximp=i32(n_shards, nb_loc),
         range_starts=i32(n_shards, r_loc),
@@ -199,13 +202,13 @@ def sharded_query_specs(
 def _local_traverse(arrays_local, blk, rest, order, bounds, *, s_pad, k,
                     budget, prune_blocks=True):
     """Run the single-node traversal on this shard for one query."""
+    blk_docs, blk_imps, blk_len, blk_maximp, range_starts = arrays_local
     dix = DeviceIndex(
-        docs=arrays_local[0], impacts=arrays_local[1],
-        blk_start=arrays_local[2], blk_len=arrays_local[3],
-        blk_maximp=arrays_local[4],
+        blk_docs=blk_docs, blk_imps=blk_imps,
+        blk_len=blk_len, blk_maximp=blk_maximp,
         bounds_dense=jnp.zeros((1, 1), jnp.int32),  # bounds arrive via tables
-        range_starts=arrays_local[5],
-        range_sizes=jnp.zeros_like(arrays_local[5]),
+        range_starts=range_starts,
+        range_sizes=jnp.zeros_like(range_starts),
     )
     res = range_daat.device_traverse(
         dix, blk, rest, order, bounds,
@@ -246,7 +249,8 @@ def make_sharded_query_fn(ctx: ShardCtx, *, s_pad: int, k: int, budget: int):
         out_i = jnp.take_along_axis(mi, sel, axis=1)
         return out_v, out_i, jax.lax.psum(jnp.sum(nr), m_axis)
 
-    arr_specs = tuple([P(m_axis, None)] * 6)
+    rows, cols = P(m_axis, None, None), P(m_axis, None)
+    arr_specs = (rows, rows, cols, cols, cols)
     fn = jax.shard_map(
         body,
         mesh=ctx.mesh,
@@ -265,7 +269,7 @@ def make_sharded_query_fn(ctx: ShardCtx, *, s_pad: int, k: int, budget: int):
     def step(arrays: ShardedIndexArrays, tables):
         blk, rest, order, bounds = tables
         return fn(
-            (arrays.docs, arrays.impacts, arrays.blk_start, arrays.blk_len,
+            (arrays.blk_docs, arrays.blk_imps, arrays.blk_len,
              arrays.blk_maximp, arrays.range_starts),
             arrays.doc_base, blk, rest, order, bounds,
         )

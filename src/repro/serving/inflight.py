@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.clustered_index import BLOCK
 from repro.core.range_daat import (
     QueryPlan,
     TraverseCarry,
@@ -318,6 +319,10 @@ class InflightServer:
         obs = self.obs
         if obs.enabled:
             obs.observe("step_ms", step_ms, server="inflight")
+            # Every slot gathers its whole block table each range step, so
+            # this is the percent of gathered lanes that held a real posting.
+            lanes = self.n_slots * self.quantum * self.buffers.front.width * BLOCK
+            obs.observe("gather_fill", 100.0 * delta / lanes, server="inflight")
             obs.gauge(
                 "slot_occupancy", float(active.sum()) / self.n_slots,
                 server="inflight",

@@ -42,8 +42,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.clustered_index import (
     BLOCK,
     IndexShard,
-    pack_dir_entries,
-    pack_docs,
     shard_cuts,
     shard_device_index,
 )
@@ -51,9 +49,9 @@ from repro.core.range_daat import (
     DeviceIndex,
     Engine,
     QueryPlan,
+    device_index,
     device_traverse,
     merge_topk,
-    pack_impacts,
 )
 from repro.distributed.sharding import retrieval_mesh
 from repro.obs.profiler import jit_cache_size
@@ -158,21 +156,31 @@ def sharded_batched_traverse(
     )
 
 
+def _stack_padded(arrs: Sequence[np.ndarray]) -> np.ndarray:
+    """Stack arrays along a new leading axis, zero-padding axis 0 to the
+    longest (at least 1, so an empty leaf still has an element to gather)."""
+    n = max(a.shape[0] for a in arrs) or 1
+    out = np.zeros((len(arrs), n) + arrs[0].shape[1:], arrs[0].dtype)
+    for s, a in enumerate(arrs):
+        out[s, : a.shape[0]] = a
+    return out
+
+
 def index_specs(axis: str, docs_format: str) -> DeviceIndex:
     """PartitionSpecs of a stacked [S, ...] ``DeviceIndex``: the shard axis
     over mesh ``axis``, every other dim (and any other mesh axis, such as
     a replica axis) replicated."""
-    row = P(axis, None)
+    row = P(axis, None)  # a vector per shard
+    grid = P(axis, None, None)  # a matrix per shard
     pack = {}
     if docs_format == "packed":
         pack = dict(pack_words=row, pack_dir=row, pack_first=row)
     return DeviceIndex(
-        docs=row,
-        impacts=row,
-        blk_start=row,
+        blk_docs=None if docs_format == "packed" else grid,
+        blk_imps=grid,
         blk_len=row,
         blk_maximp=row,
-        bounds_dense=P(axis, None, None),
+        bounds_dense=grid,
         range_starts=row,
         range_sizes=row,
         **pack,
@@ -402,65 +410,20 @@ class ShardedEngine:
             [sh.doc_base for sh in self.shards], np.int64
         )
 
-        def stack(field, pad=0, arrs=None):
-            if arrs is None:
-                arrs = [
-                    np.asarray(getattr(sh, field), np.int32) for sh in self.shards
-                ]
-            w = max((a.shape[0] for a in arrs), default=1) or 1
-            out = np.full((self.n_shards, w), pad, arrs[0].dtype if arrs else np.int32)
-            for s, a in enumerate(arrs):
-                out[s, : a.shape[0]] = a
-            return out
-
-        # bounds_dense is a planning-time structure; traversal reads bounds
-        # via the plan tables, so the device mirror carries a placeholder
-        # (the real shard-local bounds live on IndexShard.bounds_dense).
-        # Impacts upload at the engine's impact dtype — int8 keeps shard
-        # postings at 1 B/posting in HBM (DESIGN.md §8); padding lanes are
-        # never gathered (blocks only address real offsets), so the pad
-        # value is inert at either dtype.
-        if self.docs_format == "packed":
-            # Pack each shard's local docid stream against its own block
-            # geometry (deltas are shard-local, DESIGN.md §12); the stacked
-            # [S, W] leaves pad with zero words / zero directory rows, which
-            # decode to nothing because padded blocks are never addressed.
-            packed = [
-                pack_docs(sh.docs, sh.blk_start, sh.blk_len)
-                for sh in self.shards
-            ]
-            docs_dev = np.zeros((self.n_shards, 1), np.int32)
-            pack_dev = dict(
-                pack_words=stack(
-                    "words", arrs=[np.asarray(p.words, np.uint32) for p in packed]
-                ),
-                pack_dir=stack(
-                    "pack_dir", arrs=[pack_dir_entries(p) for p in packed]
-                ),
-                pack_first=stack(
-                    "pack_first", arrs=[p.blk_first for p in packed]
-                ),
+        # Each shard's device layout comes from ``device_index``, then every
+        # leaf is stacked shard-major, zero-padded to the largest shard's
+        # length. Padded blocks, words and ranges are never addressed: a
+        # shard's block ids stay below its own NB. bounds_dense is a
+        # planning-time structure (traversal reads bounds via the plan
+        # tables), so the device mirror carries a placeholder; the real
+        # shard-local bounds live on IndexShard.bounds_dense.
+        per_shard = [
+            device_index(sh, self.impact_dtype, self.docs_format)._replace(
+                bounds_dense=np.zeros((1, 1), np.int32)
             )
-        else:
-            docs_dev = stack("docs")
-            pack_dev = {}
-        host = DeviceIndex(
-            docs=docs_dev,
-            impacts=stack(
-                "impacts",
-                arrs=[
-                    pack_impacts(sh.impacts, self.impact_dtype)
-                    for sh in self.shards
-                ],
-            ),
-            blk_start=stack("blk_start"),
-            blk_len=stack("blk_len"),
-            blk_maximp=stack("blk_maximp"),
-            bounds_dense=np.zeros((self.n_shards, 1, 1), np.int32),
-            range_starts=stack("range_starts"),
-            range_sizes=stack("range_sizes"),
-            **pack_dev,
-        )
+            for sh in self.shards
+        ]
+        host = jax.tree.map(lambda *leaves: _stack_padded(leaves), *per_shard)
         doc_base = self.doc_base_host.astype(np.int32)
 
         if use_mesh is None:

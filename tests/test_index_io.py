@@ -206,20 +206,23 @@ def test_device_bytes_match_uploaded_buffers(index, impact_dtype):
     # pack_* leaves are None in the raw-int32 docid layout and accounted
     # as a single "docs" line in the packed one (tests/test_packed_postings
     # covers that path), so only materialized non-pack leaves line up 1:1.
+    # The postings' block rows are reported as "docs" and "impacts".
+    key = {"blk_docs": "docs", "blk_imps": "impacts"}
     fields = [
         n for n in eng.dix._fields
         if not n.startswith("pack_") and getattr(eng.dix, n) is not None
     ]
     for name in fields:
-        assert dev[name] == np.asarray(getattr(eng.dix, name)).nbytes, name
+        got = np.asarray(getattr(eng.dix, name)).nbytes
+        assert dev[key.get(name, name)] == got, name
     assert dev["postings"] == dev["docs"] + dev["impacts"]
-    assert dev["total"] == sum(dev[n] for n in fields)
+    assert dev["total"] == sum(dev[key.get(n, n)] for n in fields)
 
 
 def test_int8_halves_postings_hbm(index):
     d32 = index.space_report("int32")["device_bytes"]
     d8 = index.space_report("int8")["device_bytes"]
-    assert d32["impacts"] == 4 * d8["impacts"]  # 4 B -> 1 B per posting
+    assert d32["impacts"] == 4 * d8["impacts"]  # 4 B -> 1 B per block lane
     assert d32["postings"] / d8["postings"] >= 1.5  # docs stay int32
     assert d8["total"] < d32["total"]
     with pytest.raises(ValueError):

@@ -483,3 +483,27 @@ def test_traversal_ops_carry_their_layer_scopes(impl, docs_format):
     )
     parts = {p for name in re.findall(r'op_name="([^"]*)"', text) for p in name.split("/")}
     assert {"gather", "accumulate", "topk"} <= parts
+
+
+@pytest.mark.parametrize("quantum", [1, 2])
+def test_gather_fill_is_scored_postings_over_gathered_lanes(quantum):
+    """``gather_fill`` observes, per dispatch, 100 x the postings the
+    dispatch scored over slots x quantum x width x 128 gathered lanes."""
+    from repro.core.clustered_index import BLOCK
+    from repro.obs import Instrumentation
+
+    eng, queries = _small_setup(seed=23, n_ranges=6, n_queries=10)
+    width = max(eng.plan(q).blk_tab.shape[1] for q in queries)
+    obs = Instrumentation.make()
+    srv = InflightServer(
+        BatchEngine(eng, BucketSpec(max_batch=4, min_width=width)),
+        FixedBudgeter(), n_slots=4, quantum=quantum, obs=obs,
+    )
+    served = srv.replay(queries)
+    assert srv.buffers.front.width == width  # no width growth mid-run
+    hist = obs.metrics.histogram("gather_fill")
+    assert hist.count(server="inflight") == srv.steps_run
+    lanes = 4 * quantum * width * BLOCK
+    scored = hist.mean(server="inflight") * srv.steps_run * lanes / 100.0
+    assert scored == pytest.approx(sum(s.result.postings for s in served))
+    assert 0.0 < hist.mean(server="inflight") < 100.0

@@ -200,13 +200,13 @@ def test_space_report_matches_uploaded_buffers():
                 + eng.dix.pack_dir.nbytes
                 + eng.dix.pack_first.nbytes
             )
-            # The 4-byte docs placeholder is jit plumbing, not postings.
-            assert eng.dix.docs.nbytes == 4
+            # Packed docids come from the word stream: no docid rows.
+            assert eng.dix.blk_docs is None
         else:
-            uploaded = eng.dix.docs.nbytes
+            uploaded = eng.dix.blk_docs.nbytes
             assert eng.dix.pack_words is None
         assert dev["docs"] == uploaded, (docs_format, impact_dtype)
-        assert dev["impacts"] == eng.dix.impacts.nbytes
+        assert dev["impacts"] == eng.dix.blk_imps.nbytes
         assert dev["postings"] == dev["docs"] + dev["impacts"]
         # Formula-only path (manifest metadata) agrees with the index path.
         meta = device_bytes_report(

@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.core.clustered_index import BLOCK
 from repro.core.range_daat import (
     DeviceIndex,
     TopKState,
@@ -32,8 +33,8 @@ from repro.kernels.range_scorer import kernel
 from repro.serving.sharded import index_specs, make_mesh_dispatch
 
 # Widths of the chip_smoke.py index (about 20M int8 postings over 64
-# ranges) and of its in-flight server (16 slots, 64-block tables).
-NNZ = 20_000_000
+# ranges, held as N_BLOCKS block rows of BLOCK lanes) and of its in-flight
+# server (16 slots, 64-block tables).
 N_BLOCKS = 4_800_000
 N_WORDS = 8_000_000
 N_TERMS = 50_000
@@ -89,9 +90,8 @@ def _spec(sharding):
 def _index(s, docs_format, lead=()):
     packed = docs_format == "packed"
     return DeviceIndex(
-        docs=s(lead + ((1,) if packed else (NNZ,))),
-        impacts=s(lead + (NNZ,), jnp.int8),
-        blk_start=s(lead + (N_BLOCKS,)),
+        blk_docs=None if packed else s(lead + (N_BLOCKS, BLOCK)),
+        blk_imps=s(lead + (N_BLOCKS, BLOCK), jnp.int8),
         blk_len=s(lead + (N_BLOCKS,)),
         blk_maximp=s(lead + (N_BLOCKS,)),
         bounds_dense=s(lead + ((1, 1) if lead else (N_TERMS, R))),
