@@ -19,25 +19,24 @@ import argparse  # noqa: E402
 import json  # noqa: E402
 
 from bench import gen  # noqa: E402
-from bench.reference import Answer, Reference  # noqa: E402
+from bench.reference import Answer  # noqa: E402
 from bench.spec import load_cell  # noqa: E402
 
 CONTROL_BITS = 4
 
 
 def control_reading(cell, seed: int, seconds: float) -> dict:
-    cfg = cell.config
-    corpus = gen.make_corpus(cfg, seed)
-    order, ends = gen.topic_arrangement(corpus)
+    cfg, inputs = cell.config, cell.inputs
+    corpus = inputs.make_corpus(cfg, seed)
+    order, ends = inputs.arrange(corpus)
     due = gen.make_schedule(cell.traffic, seconds, seed)
-    queries = gen.make_queries(corpus, cell.traffic, len(due), seed)
-    args = (int(cfg["k"]), float(cfg["bm25_k1"]), float(cfg["bm25_b"]))
-    ref = Reference(corpus, order, ends, args[0], int(cfg["impact_bits"]), *args[1:])
-    low = Reference(corpus, order, ends, args[0], CONTROL_BITS, *args[1:])
+    queries = inputs.make_queries(corpus, cell.traffic, len(due), seed)
+    ref = cell.reference.make_reference(corpus, order, ends, cfg)
+    low = cell.reference.make_reference(corpus, order, ends, dict(cfg, impact_bits=CONTROL_BITS))
     wrong = 0
     for q in queries:
-        ids, sc = low.topk(low.scores(q))
-        wrong += not ref.check(q, Answer(ids, sc, ref.n_ranges, "exhausted"), cell.guarantee)
+        ids, sc = low.expected(q, None, "rank_safe")
+        wrong += not ref.check(q, Answer(ids, sc, len(ends), "exhausted"), cell.guarantee)
     return {"seed": seed, "wrong_answers": wrong, "of": len(queries)}
 
 

@@ -1,13 +1,14 @@
 """From a ``jax.profiler`` trace to device numbers.
 
-``load_events`` flattens an ``.xplane.pb`` into plain events; ``reduce``
-turns them into the numbers the per-layer metrics read:
+``bench/phasetrace.load_events`` flattens an ``.xplane.pb`` into plain
+events; ``reduce`` turns them into the numbers the per-layer metrics read:
 
 * the traced window: the host span ``bench.window`` the loop writes;
 * busy time: the union of the device's operations (the ``XLA Ops`` line of
   each ``/device:`` plane) clipped to the window, averaged over devices;
 * per-program device time and dispatch count: the ``XLA Modules`` events
-  whose name holds the jitted function's name;
+  whose name holds the jitted function's name (``traversal`` sums them over
+  a layout's programs);
 * the device operations that took most time;
 * the idle gaps (the window less the busy union), each named by the
   ``bench.*`` host span that overlaps it most, the innermost on a tie.
@@ -17,12 +18,11 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
-import glob
 import json
 from collections import defaultdict
 from pathlib import Path
 
-__all__ = ["Event", "load_events", "read_events", "reduce"]
+__all__ = ["Event", "read_events", "reduce", "traversal"]
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -42,28 +42,6 @@ class Event:
     @property
     def end_ns(self) -> float:
         return self.start_ns + self.dur_ns
-
-
-def load_events(trace_dir: str) -> list[Event]:
-    """Device events and the benchmark's host spans of the newest trace."""
-    from jax.profiler import ProfileData
-
-    paths = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
-    if not paths:
-        return []
-    pd = ProfileData.from_file(paths[-1])
-    out = []
-    for plane in pd.planes:
-        device = plane.name.startswith("/device:")
-        for line in plane.lines:
-            if device and line.name not in (OPS_LINE, MODULES_LINE):
-                continue
-            for ev in line.events:
-                if device or ev.name.startswith(HOST_PREFIX):
-                    # An op's event name is its whole HLO line; keep its head.
-                    name = ev.name[:OP_NAME_CHARS] if line.name == OPS_LINE else ev.name
-                    out.append(Event(plane.name, line.name, name, ev.start_ns, ev.duration_ns))
-    return out
 
 
 def read_events(path: Path) -> list[Event]:
@@ -156,3 +134,17 @@ def reduce(events: list[Event], programs: tuple[str, ...] = ()) -> dict | None:
         "idle_by_phase": dict(sorted(idle_by_phase.items(), key=lambda kv: -kv[1])),
         "window_ns": (lo, hi),
     }
+
+
+def traversal(red: dict | None) -> tuple[float, int] | None:
+    """(device seconds, dispatches) of the traversal in the traced window, or None.
+
+    ``red`` was reduced over a layout's ``PROGRAMS``: their seconds are
+    summed, and the first, dispatched once a server step, counts the
+    dispatches.
+    """
+    progs = list((red or {}).get("programs", {}).values())
+    if not progs or not progs[0]["dispatches"]:
+        return None
+    seconds = sum(p["seconds"] for p in progs)
+    return (seconds, progs[0]["dispatches"]) if seconds > 0 else None

@@ -1,5 +1,10 @@
 """Inputs of a run, all made from ``--seed``: corpus, queries, arrival schedule.
 
+The default ``inputs`` module of a configuration (``bench/spec.py``): the
+corpus, its arrangement into ranges, the queries, and the program's index
+built from them (``build_index``). The arrival schedule is the traffic's,
+for every configuration.
+
 The semantics follow the repo's planted-topic generator (``repro.data.synth``):
 each topic owns a permuted Zipf distribution over the vocabulary, a document
 draws its tokens from its topic's distribution, and a query draws distinct
@@ -23,10 +28,11 @@ import numpy as np
 
 __all__ = [
     "Corpus",
+    "arrange",
+    "build_index",
     "make_corpus",
     "make_queries",
     "make_schedule",
-    "topic_arrangement",
 ]
 
 # Independent random streams of one seed.
@@ -132,7 +138,7 @@ def make_corpus(cfg: dict, seed: int) -> Corpus:
     )
 
 
-def topic_arrangement(corpus: Corpus) -> tuple[np.ndarray, np.ndarray]:
+def arrange(corpus: Corpus) -> tuple[np.ndarray, np.ndarray]:
     """Docs sorted by topic, one range per non-empty topic.
 
     Returns ``(order, ends)``: ``order[new_id] = old_id`` and ``ends[r]``
@@ -143,6 +149,34 @@ def topic_arrangement(corpus: Corpus) -> tuple[np.ndarray, np.ndarray]:
     counts = np.bincount(corpus.doc_topic, minlength=corpus.n_topics)
     ends = np.cumsum(counts[counts > 0]).astype(np.int64)
     return order, ends
+
+
+def _program_corpus(c: Corpus):
+    from repro.data.synth import Corpus as ProgramCorpus
+
+    return ProgramCorpus(
+        n_docs=c.n_docs,
+        n_terms=c.n_terms,
+        doc_ptr=c.doc_ptr,
+        doc_terms=c.doc_terms,
+        doc_tfs=c.doc_tfs,
+        doc_topic=c.doc_topic,
+        n_topics=c.n_topics,
+    )
+
+
+def build_index(cfg: dict, corpus: Corpus, order: np.ndarray, ends: np.ndarray):
+    """The program's ``build_index`` over the corpus, at the configuration's BM25 and impact bits."""
+    from repro.core import build_index as program_build_index
+    from repro.core.bm25 import BM25Params
+    from repro.core.reorder import Arrangement
+
+    return program_build_index(
+        _program_corpus(corpus),
+        arrangement=Arrangement(order, ends, "clustered"),
+        bits=int(cfg["impact_bits"]),
+        params=BM25Params(k1=float(cfg["bm25_k1"]), b=float(cfg["bm25_b"])),
+    )
 
 
 def _length_counts(n: int, length_pmf: dict) -> tuple[np.ndarray, np.ndarray]:
@@ -157,7 +191,7 @@ def make_queries(
     """``[n, max_len]`` int32 query terms, -1 padded, each row sorted.
 
     ``lead_in`` draws from a stream of its own: queries of the same mix
-    that the server saw before the window (see ``bench/system.lead_in``).
+    that the server saw before the window (see ``bench/layouts/single.lead_in``).
 
     Keys read from the traffic mix: ``query_lengths`` (length -> share),
     ``term_pool`` (candidates per topic), ``df_min``, ``df_max_frac``.

@@ -1,8 +1,8 @@
-"""Device milliseconds per dispatch of ``batched_traverse_resume``, from the trace."""
+"""Device milliseconds per dispatch of the layout's traversal programs, from the trace."""
 
-from bench.metrics._traverse import program
+from bench.devtrace import traversal
 
 
 def read(run):
-    p = program(run)
+    p = traversal(run.trace)
     return None if p is None else 1e3 * p[0] / p[1]

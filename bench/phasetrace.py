@@ -14,8 +14,8 @@ harness's own ``bench.*`` spans. The program names its own work too:
   ``hlo_scopes`` maps instruction names to scopes from the compiled
   program's HLO text (``traverse_hlo``), read once in set-up.
 
-This module reads both. ``load_events`` keeps devtrace's events, the
-``repro.*`` spans, and each op's scope. ``reduce`` keeps devtrace's window,
+This module reads both. ``load_events`` keeps the device events, the
+``bench.*`` and ``repro.*`` host spans, and each op's scope. ``reduce`` keeps devtrace's window,
 busy time and per-program numbers, prefixes each of ``device_ops`` with its
 scope, and, where the trace holds scopes and phases, adds or replaces:
 
@@ -31,7 +31,8 @@ scope, and, where the trace holds scopes and phases, adds or replaces:
 * ``step_idle_s`` and ``step_idle_named_s``: idle seconds inside
   ``bench.step``, and the part of them under a ``repro.*`` phase.
 
-``per_dispatch`` turns these into milliseconds per traversal dispatch.
+``per_dispatch`` turns these into milliseconds per traversal dispatch
+(``devtrace.traversal``).
 """
 
 from __future__ import annotations
@@ -58,7 +59,6 @@ __all__ = [
     "write_events",
 ]
 
-PROGRAM = "batched_traverse_resume"
 SCOPES = ("gather", "accumulate", "topk")
 STEP_PHASES = ("admit", "launch", "plan_ahead", "fetch", "retire")
 PROGRAM_PREFIX = "repro."
@@ -252,7 +252,7 @@ def _idle_pieces(events, first_device, lo, hi):
             yield (y - x) * 1e-9, inner, owner, any(h.name == STEP for h in cover)
 
 
-def reduce(events: list, programs: tuple[str, ...] = (PROGRAM,)) -> dict | None:
+def reduce(events: list, programs: tuple[str, ...]) -> dict | None:
     """devtrace's numbers with phases and scopes added; None when there is nothing to read."""
     red = devtrace.reduce(events, programs)
     if red is None:
@@ -285,10 +285,10 @@ def per_dispatch(red: dict | None) -> dict[str, float]:
     Empty where the trace holds no scoped op and no ``repro.*`` phase, as a
     program without them gives.
     """
-    prog = (red or {}).get("programs", {}).get(PROGRAM)
-    if not prog or not prog["dispatches"]:
+    trav = devtrace.traversal(red)
+    if trav is None:
         return {}
-    n = prog["dispatches"]
+    n = trav[1]
     out = {}
     if "scopes" in red:
         out.update({f"{s}_device_ms": 1e3 * red["scopes"].get(s, 0.0) / n for s in SCOPES})

@@ -1,5 +1,8 @@
 """Plain reference: exhaustive scoring of the generated corpus, and the check.
 
+The default ``reference`` module of a configuration (``bench/spec.py``):
+``make_reference(corpus, order, ends, cfg)``.
+
 Independent of the program: it takes the corpus, the topic arrangement and
 the configuration, and computes everything else itself. Its semantics are
 the configuration's: BM25 (``bm25_k1``, ``bm25_b``, Lucene's non-negative
@@ -28,7 +31,7 @@ import numpy as np
 
 from bench.gen import Corpus
 
-__all__ = ["Answer", "Reference"]
+__all__ = ["Answer", "Reference", "make_reference"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,3 +146,11 @@ class Reference:
         got = got[(got >= 0) & (got < self.n_docs)]
         found = int((acc[got] >= sc[-1]).sum())
         return min(found, ids.size), int(ids.size)
+
+
+def make_reference(corpus: Corpus, order: np.ndarray, ends: np.ndarray, cfg: dict) -> Reference:
+    """The reference at the configuration's k, impact bits and BM25."""
+    return Reference(
+        corpus, order, ends, int(cfg["k"]), int(cfg["impact_bits"]),
+        float(cfg["bm25_k1"]), float(cfg["bm25_b"]),
+    )

@@ -4,18 +4,20 @@
 
 Set-up (counted in ``setup_s``, from process start to the first due query):
 the corpus and the queries from ``--seed``, the program's index for that
-corpus (built by ``build_index``, or loaded from ``.cache/bench/index``
-when this seed and these program sources built it before), the upload to
-the chip, and the compile of every program shape the traffic reaches (read
-from the compile cache in ``.cache/bench/jax`` after the first run). Then
-the open loop drives the server for ``--seconds``, waits for late answers,
-and checks every answer against the plain reference under the
-configuration's guarantee.
+corpus (built by the configuration's ``inputs`` module, or loaded from
+``.cache/bench/index`` when this seed and these sources built it before),
+the upload to the cell's chips, and the compile of every program shape the
+traffic reaches (read from the compile cache in ``.cache/bench/jax`` after
+the first run). Then the open loop drives the server for ``--seconds``,
+waits for late answers, and checks every answer against the configuration's
+plain reference under its guarantee. What is built, served and compared
+with is the configuration's choice (``bench/spec.py``).
 
 With ``--trace 1`` a profiler trace of a few seconds in the middle of the
-window gives the device numbers, and the result carries the per-layer
-metrics instead of the end-to-end ones. Without a TPU, or with fewer chips
-than the cell asks for, it exits non-zero and prints no result.
+window gives the device numbers, the server names its step's phases in it,
+and the result carries the per-layer metrics instead of the end-to-end
+ones. Without a TPU, or with fewer chips than the cell asks for, it exits
+non-zero and prints no result.
 """
 
 import time
@@ -37,13 +39,12 @@ sys.path[:1] = [str(_ROOT), str(_ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import devtrace, gen, loop, system  # noqa: E402
+from bench import gen, loop, phasetrace, system  # noqa: E402
 from bench.peaks import peak  # noqa: E402
-from bench.reference import Answer, Reference  # noqa: E402
+from bench.reference import Answer  # noqa: E402
 from bench.spec import ROOT, Cell, load_cell, metric_reader  # noqa: E402
 
 CACHE = ROOT / ".cache" / "bench"
-TRACED_PROGRAMS = ("batched_traverse_resume",)
 
 
 @dataclasses.dataclass
@@ -79,35 +80,42 @@ def run_cell(
     grace_s: float = loop.GRACE_S,
     log=print,
 ) -> dict:
-    cfg, traffic = cell.config, cell.traffic
+    cfg, traffic, inputs, layout = cell.config, cell.traffic, cell.inputs, cell.layout
     devices = device_check(cell.chips)
     system.use_compile_cache(Path(cache_dir) / "jax")
     clock = system.CompileClock()
 
     t = time.perf_counter()
-    corpus = gen.make_corpus(cfg, seed)
-    order, ends = gen.topic_arrangement(corpus)
+    corpus = inputs.make_corpus(cfg, seed)
+    order, ends = inputs.arrange(corpus)
     due = gen.make_schedule(traffic, seconds, seed)
-    queries = gen.make_queries(corpus, traffic, len(due), seed)
+    queries = inputs.make_queries(corpus, traffic, len(due), seed)
     t_gen = time.perf_counter() - t
 
     index, how, t_index = system.get_index(
-        cfg, cell.config_file, corpus, order, ends, seed, ROOT, Path(cache_dir) / "index", log
+        cell, corpus, order, ends, seed, ROOT, Path(cache_dir) / "index", log
     )
     t = time.perf_counter()
-    engine = system.make_engine(index, cfg)
-    import jax
-
-    jax.block_until_ready(engine.dix)
+    engine = layout.make_engine(index, cfg, devices)
     t_upload = time.perf_counter() - t
     c0, n0 = clock.seconds, clock.compiles
     t = time.perf_counter()
-    past = gen.make_queries(corpus, traffic, int(traffic["lead_in_queries"]), seed, lead_in=True)
+    past = inputs.make_queries(corpus, traffic, int(traffic["lead_in_queries"]), seed, lead_in=True)
     seen = np.concatenate([past, queries])
-    system.warm(engine, cfg, seen, log)
+    layout.warm(engine, cfg, seen, log)
+    import jax
+
+    from repro.obs import NOOP, NoopInstrumentation
+
+    # Traced, the server names its step's phases in the profiler's trace;
+    # untraced, it runs with no instrumentation at all.
+    obs = NoopInstrumentation(annotate=jax.profiler.TraceAnnotation) if trace else NOOP
     spans = system.Spans(annotate=trace)
-    server = system.make_server(engine, cfg, traffic, spans)
-    system.lead_in(server, engine, seen, log)
+    server = layout.make_server(engine, cfg, traffic, spans, obs)
+    layout.lead_in(server, engine, seen, log)
+    # The traversal's ops carry their scopes only in the compiled program's
+    # text, which a TPU trace does not hold: read it once, before the window.
+    op_scopes = phasetrace.hlo_scopes(layout.program_hlo(server)) if trace else None
     spans.clear()
     t_warm = time.perf_counter() - t
     # What set-up left behind is the serving process's long-lived state, as
@@ -142,7 +150,7 @@ def run_cell(
     red = None
     postings_traced = 0
     if tw is not None and tw.started is not None:
-        red = devtrace.reduce(devtrace.load_events(tw.directory), TRACED_PROGRAMS)
+        red = phasetrace.reduce(phasetrace.load_events(tw.directory, op_scopes), layout.PROGRAMS)
         ends_s = np.asarray(d.step_end_s)
         steps = np.asarray(d.postings)
         postings_traced = int(steps[(ends_s >= tw.started) & (ends_s <= tw.stopped)].sum())
@@ -151,10 +159,7 @@ def run_cell(
 
     # The reference, once the window has closed and the device is read.
     t = time.perf_counter()
-    ref = Reference(
-        corpus, order, ends, int(cfg["k"]), int(cfg["impact_bits"]),
-        float(cfg["bm25_k1"]), float(cfg["bm25_b"]),
-    )
+    ref = cell.reference.make_reference(corpus, order, ends, cfg)
     wrong, found, wanted, reasons = 0, 0, 0, []
     for j, sq in enumerate(d.served):
         if sq is None:

@@ -28,6 +28,7 @@ import numpy as np  # noqa: E402
 
 from bench import gen, loop, system  # noqa: E402
 from bench.spec import ROOT, load_cell  # noqa: E402
+from repro.obs import NOOP  # noqa: E402
 
 
 def _quarter_backlog(backlog, seconds, q):
@@ -39,30 +40,30 @@ def _keeps_up(r: dict) -> bool:
     return r["completed_qps"] >= 0.95 * r["offered_qps"] and r["backlog_q4"] <= 1.25 * r["backlog_q2"] + 2
 
 
-def _sweep_seed(cell, seed: int, rates: list, args, clock) -> float:
-    cfg = cell.config
-    corpus = gen.make_corpus(cfg, seed)
-    order, ends = gen.topic_arrangement(corpus)
+def _sweep_seed(cell, seed: int, rates: list, args, clock, devices) -> float:
+    cfg, inputs, layout = cell.config, cell.inputs, cell.layout
+    corpus = inputs.make_corpus(cfg, seed)
+    order, ends = inputs.arrange(corpus)
     index, how, t_index = system.get_index(
-        cfg, cell.config_file, corpus, order, ends, seed, ROOT, ROOT / ".cache" / "bench" / "index"
+        cell, corpus, order, ends, seed, ROOT, ROOT / ".cache" / "bench" / "index"
     )
     print(f"seed {seed}: index {how} in {t_index:.1f}s: {index.nnz} postings")
-    engine = system.make_engine(index, cfg)
+    engine = layout.make_engine(index, cfg, devices)
     mixes = []
     for j, rate in enumerate(rates):
         traffic = dict(cell.traffic, rate_qps=rate)
         if args.sla_ms is not None:
             traffic["sla_ms"] = args.sla_ms
         due = gen.make_schedule(traffic, args.seconds, seed + j)
-        mixes.append((traffic, due, gen.make_queries(corpus, traffic, len(due), seed + j)))
-    past = gen.make_queries(corpus, cell.traffic, int(cell.traffic["lead_in_queries"]), seed, lead_in=True)
+        mixes.append((traffic, due, inputs.make_queries(corpus, traffic, len(due), seed + j)))
+    past = inputs.make_queries(corpus, cell.traffic, int(cell.traffic["lead_in_queries"]), seed, lead_in=True)
     seen = np.concatenate([past] + [q for _, _, q in mixes])
-    system.warm(engine, cfg, seen)
+    layout.warm(engine, cfg, seen)
     knee = 0.0
     for rate, (traffic, due, queries) in zip(rates, mixes):
         spans = system.Spans()
-        server = system.make_server(engine, cfg, traffic, spans)
-        system.lead_in(server, engine, seen)
+        server = layout.make_server(engine, cfg, traffic, spans, NOOP)
+        layout.lead_in(server, engine, seen)
         spans.clear()
         t0 = time.perf_counter()
         d = loop.drive(server, queries, due, args.seconds, spans, clock, grace_s=5.0)
@@ -101,7 +102,7 @@ def main(argv=None, device_check=system.check_device) -> int:
     rates = [float(r) for r in args.rates.split(",")]
     cell = load_cell(args.workload)
     try:
-        device_check(cell.chips)
+        devices = device_check(cell.chips)
     except system.NoChip as e:
         print(f"refused: {e}", file=sys.stderr)
         return 2
@@ -109,7 +110,7 @@ def main(argv=None, device_check=system.check_device) -> int:
     clock = system.CompileClock()
     knees = {}
     for seed in args.seeds:
-        knees[seed] = _sweep_seed(cell, seed, rates, args, clock)
+        knees[seed] = _sweep_seed(cell, seed, rates, args, clock, devices)
     print(json.dumps({"knees": knees, "lowest": min(knees.values())}), flush=True)
     return 0
 

@@ -1,11 +1,11 @@
-"""The system under test, as the benchmark drives it.
+"""What every run shares around the system under test.
 
-Everything the program contributes to a run goes through here: its index
-build (``build_index``) or a stored copy of it (``repro.index_io``), its
-engine (``core.range_daat.Engine`` at the default scorer and docid format),
-its serving loop (``InflightServer`` over ``BatchEngine``) and budgeter
-(``SlaBudgeter`` with the ``Reactive`` policy). The benchmark adds only
-spans around its calls into the program.
+The look for the chip, the compile cache and its clock, the program's index
+(built by the configuration's ``inputs`` module, or a stored copy of it read
+back through ``repro.index_io``), and the harness's own spans. The engine
+and the server come from the configuration's ``layout`` module
+(``bench/layouts``); the benchmark adds only spans around its calls into
+the program.
 """
 
 from __future__ import annotations
@@ -17,27 +17,21 @@ import shutil
 import time
 from pathlib import Path
 
-import numpy as np
-
-from bench.gen import Corpus
-
 __all__ = [
     "CompileClock",
     "NoChip",
+    "Spans",
     "check_device",
     "get_index",
     "index_key",
-    "lead_in",
-    "make_engine",
-    "make_server",
     "source_hash",
     "use_compile_cache",
-    "warm",
 ]
 
 # Sources that decide what an index holds, the program's and the harness's
 # own (the corpus and the build's arguments): a change to any of them must
-# never be served an index its parent built.
+# never be served an index its parent built. A configuration's own
+# ``inputs``, ``reference`` and ``layout`` modules are hashed beside them.
 INDEX_SOURCES = ("src/repro/core", "src/repro/data", "src/repro/index_io", "bench/gen.py", "bench/system.py")
 KEEP_INDEXES = 16  # stored indexes kept per configuration: two sets of six seeds
 
@@ -108,49 +102,29 @@ def source_hash(root: Path) -> str:
     return h.hexdigest()
 
 
-def index_key(config_file: Path, seed: int, root: Path) -> str:
+def index_key(config_file: Path, seed: int, root: Path, modules=()) -> str:
+    """Key of a stored index: configuration, seed, index sources and ``modules``' files."""
     h = hashlib.sha1(config_file.read_bytes())
     h.update(str(int(seed)).encode())
     h.update(source_hash(root).encode())
+    for p in modules:
+        h.update(Path(p).name.encode())
+        h.update(Path(p).read_bytes())
     return h.hexdigest()[:20]
 
 
-def _program_corpus(c: Corpus):
-    from repro.data.synth import Corpus as ProgramCorpus
-
-    return ProgramCorpus(
-        n_docs=c.n_docs,
-        n_terms=c.n_terms,
-        doc_ptr=c.doc_ptr,
-        doc_terms=c.doc_terms,
-        doc_tfs=c.doc_tfs,
-        doc_topic=c.doc_topic,
-        n_topics=c.n_topics,
-    )
-
-
-def _build(cfg: dict, corpus: Corpus, order: np.ndarray, ends: np.ndarray):
-    from repro.core import build_index
-    from repro.core.bm25 import BM25Params
-    from repro.core.reorder import Arrangement
-
-    return build_index(
-        _program_corpus(corpus),
-        arrangement=Arrangement(order, ends, "clustered"),
-        bits=int(cfg["impact_bits"]),
-        params=BM25Params(k1=float(cfg["bm25_k1"]), b=float(cfg["bm25_b"])),
-    )
-
-
-def get_index(cfg, config_file, corpus, order, ends, seed, root, cache_dir, log=print):
+def get_index(cell, corpus, order, ends, seed, root, cache_dir, log=print):
     """The program's index for this corpus: stored copy if there is one, else built.
 
-    Returns ``(index, "built" | "loaded", seconds)``. A stored copy that does
-    not load is reported and replaced by a build.
+    The cell's ``inputs`` module builds it. Returns ``(index, "built" |
+    "loaded", seconds)``. A stored copy that does not load is reported and
+    replaced by a build.
     """
     from repro import index_io
 
-    path = Path(cache_dir) / f"{cfg['name']}-{index_key(Path(config_file), seed, root)}"
+    cfg = cell.config
+    key = index_key(Path(cell.config_file), seed, root, cell.module_files)
+    path = Path(cache_dir) / f"{cfg['name']}-{key}"
     t0 = time.perf_counter()
     if path.is_dir():
         try:
@@ -160,7 +134,7 @@ def get_index(cfg, config_file, corpus, order, ends, seed, root, cache_dir, log=
         except Exception as e:  # noqa: BLE001 - any unreadable copy is rebuilt
             log(f"index cache: {path.name} did not load ({type(e).__name__}: {e}); building")
             shutil.rmtree(path, ignore_errors=True)
-    index = _build(cfg, corpus, order, ends)
+    index = cell.inputs.build_index(cfg, corpus, order, ends)
     seconds = time.perf_counter() - t0
     path.parent.mkdir(parents=True, exist_ok=True)
     index_io.save_index(index, str(path), impact_dtype=cfg["impact_dtype"], overwrite=True)
@@ -168,12 +142,6 @@ def get_index(cfg, config_file, corpus, order, ends, seed, root, cache_dir, log=
     for old in stored[:-KEEP_INDEXES]:
         shutil.rmtree(old, ignore_errors=True)
     return index, "built", seconds
-
-
-def make_engine(index, cfg: dict):
-    from repro.core import Engine
-
-    return Engine(index, k=int(cfg["k"]), impact_dtype=cfg["impact_dtype"])
 
 
 class Spans:
@@ -201,92 +169,3 @@ class Spans:
         import jax
 
         return jax.profiler.TraceAnnotation(f"bench.{name}")
-
-
-def make_server(engine, cfg: dict, traffic: dict, spans: Spans):
-    """A fresh ``InflightServer`` with the cell's budgeter, planning timed."""
-    from repro.core.anytime import Reactive
-    from repro.serving import BatchEngine, BucketSpec, InflightServer, SlaBudgeter
-
-    class TimedBatchEngine(BatchEngine):
-        def plan(self, q_terms):
-            with spans.phase("plan"):
-                t0 = time.perf_counter()
-                p = super().plan(q_terms)
-                spans.plan.append(time.perf_counter() - t0)
-                spans.plan_start.append(t0)
-            return p
-
-    sla = traffic.get("sla_ms")
-    if sla is None:
-        budgeter = SlaBudgeter(sla_ms=float("inf"))
-    else:
-        budgeter = SlaBudgeter(sla_ms=float(sla), policy=Reactive(**traffic.get("policy", {})))
-    n_slots = int(cfg["n_slots"])
-    return InflightServer(
-        TimedBatchEngine(engine, BucketSpec(max_batch=n_slots)),
-        budgeter,
-        n_slots=n_slots,
-        quantum=int(cfg["quantum"]),
-    )
-
-
-def plan_widths(index, queries: np.ndarray) -> np.ndarray:
-    """Block-table width of each query's plan: its widest range's block count."""
-    nb = np.zeros((index.n_terms + 1, index.n_ranges), np.int64)
-    term = np.repeat(np.arange(index.n_terms), np.diff(index.tr_ptr))
-    nb[term, index.tr_range] = index.tr_blk_end - index.tr_blk_start
-    q = np.where(queries >= 0, queries, index.n_terms)  # padding -> zero row
-    return nb[q].sum(axis=1).max(axis=1)
-
-
-def _serve_until_idle(server) -> bool:
-    """Step until every submitted query is answered; False if one never is.
-
-    A query takes at most one step per range; a program that never
-    finishes it is left to the window's check to catch.
-    """
-    for _ in range(2 * server.n_ranges + 2):
-        if not (server.pending or server.active):
-            return True
-        server.step()
-    return not (server.pending or server.active)
-
-
-def warm(engine, cfg: dict, queries: np.ndarray, log=print) -> list[int]:
-    """Compile the plan conversion of every width bucket the traffic reaches.
-
-    A plan's block table is padded to its width bucket and converted on the
-    device, one program per bucket; one query of each bucket is planned here
-    so that none compiles in the window. The traversal program compiles in
-    ``lead_in``, at the one width the window runs at.
-    """
-    from repro.serving import BucketSpec
-
-    spec = BucketSpec(max_batch=int(cfg["n_slots"]))
-    widths = plan_widths(engine.index, queries)
-    buckets = np.asarray([spec.width_bucket(int(w)) for w in widths])
-    ladder = sorted(set(buckets.tolist()))
-    for w in ladder:
-        engine.plan(queries[int(np.argmax(buckets == w))])
-    log(f"warm: plan widths {ladder} (plans {int(widths.min())}..{int(widths.max())} blocks)")
-    return ladder
-
-
-def lead_in(server, engine, queries: np.ndarray, log=print) -> int:
-    """Bring a fresh server to the width a long-running one has.
-
-    The slot table only widens, to the widest plan served so far, and every
-    step pays for the table's width. So a long-running server sits at the
-    width of the widest plan its traffic has sent. ``queries`` are the
-    mix's lead-in (the server's past) and the window's own; the widest of
-    them is served here, through the server's own path, so the window runs
-    at one width and compiles nothing.
-    """
-    widths = plan_widths(engine.index, queries)
-    server.submit(queries[int(np.argmax(widths))])
-    if not _serve_until_idle(server):
-        log("lead-in: the widest query was not answered")
-    width = server.buffers.front.width
-    log(f"lead-in: widest of {len(queries)} plans {int(widths.max())} blocks, slot table width {width}")
-    return width

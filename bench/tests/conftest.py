@@ -41,24 +41,36 @@ def tiny_traffic(rate=20.0, sla=False):
     return t
 
 
+# The modules a configuration may name, as test fixtures that record their calls.
+OWN_MODULES = {
+    "inputs": "tests/data/fixture_inputs.py",
+    "reference": "tests/data/fixture_reference.py",
+    "layout": "tests/data/fixture_layout.py",
+}
+
+
 @pytest.fixture
 def tiny_root(tmp_path):
-    """A root with cells ``tiny.safe``, ``tiny.sla`` and ``tinyk.safe`` (k=100)."""
+    """A root with cells ``tiny.safe``, ``tiny.sla``, ``tinyk.safe`` (k=100) and
+    ``tinyown.safe``, whose configuration names its own modules (``OWN_MODULES``)."""
     (tmp_path / "configs").mkdir()
     (tmp_path / "traffic").mkdir()
     (tmp_path / "configs/tiny.json").write_text(json.dumps(dict(tiny_config(), name="tiny")))
     (tmp_path / "configs/tinyk.json").write_text(json.dumps(dict(tiny_config(k=100), name="tinyk")))
+    own = dict(tiny_config(), name="tinyown", **OWN_MODULES)
+    (tmp_path / "configs/tinyown.json").write_text(json.dumps(own))
     (tmp_path / "traffic/safe.json").write_text(json.dumps(tiny_traffic()))
     (tmp_path / "traffic/sla.json").write_text(json.dumps(tiny_traffic(sla=True)))
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
     spec["configs"] = [
         {"name": n, "source": "test", "file": f"configs/{n}.json", "reduced": [], "why": "test"}
-        for n in ("tiny", "tinyk")
+        for n in ("tiny", "tinyk", "tinyown")
     ]
     spec["workloads"] = [
         {"name": "tiny.safe", "config": "tiny", "traffic": "safe", "chips": 1, "why": "test"},
         {"name": "tiny.sla", "config": "tiny", "traffic": "sla", "chips": 1, "why": "test"},
         {"name": "tinyk.safe", "config": "tinyk", "traffic": "safe", "chips": 1, "why": "test"},
+        {"name": "tinyown.safe", "config": "tinyown", "traffic": "safe", "chips": 1, "why": "test"},
     ]
     as_tiny = {"web-isn.safe": "tiny.safe", "passage-isn.safe": "tinyk.safe"}
     for m in spec["end_to_end"] + spec["per_layer"]:

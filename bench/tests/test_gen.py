@@ -53,7 +53,7 @@ def test_schedule_follows_the_phases():
 
 def test_topic_arrangement_is_a_partition_by_topic():
     c, _, _ = _inputs(5)
-    order, ends = gen.topic_arrangement(c)
+    order, ends = gen.arrange(c)
     assert np.array_equal(np.sort(order), np.arange(c.n_docs))
     topics = c.doc_topic[order]
     starts = np.concatenate([[0], ends[:-1]])

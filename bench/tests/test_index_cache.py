@@ -1,11 +1,10 @@
 """The stored index: keyed by configuration, seed and index-building sources."""
 
-import json
-
 import numpy as np
 
 from bench import gen, system
-from bench.tests.conftest import REPO, tiny_config
+from bench.spec import load_cell
+from bench.tests.conftest import REPO
 
 
 def _tree(root, body="x = 1\n"):
@@ -37,20 +36,34 @@ def test_key_follows_config_seed_and_index_sources(tmp_path):
     assert system.index_key(cfg, 5, tmp_path) != key
 
 
-def test_stored_index_loads_and_a_broken_one_is_rebuilt(tmp_path):
-    cfg = dict(tiny_config(), name="tiny")
-    cfg_file = tmp_path / "tiny.json"
-    cfg_file.write_text(json.dumps(cfg))
-    corpus = gen.make_corpus(cfg, 9)
-    order, ends = gen.topic_arrangement(corpus)
+def test_key_follows_the_modules_a_configuration_names(tmp_path):
+    _tree(tmp_path)
+    cfg = tmp_path / "cfg.json"
+    mods = [tmp_path / "inputs.py", tmp_path / "layout.py"]
+    for m in mods:
+        m.write_text("x = 1\n")
+    key = system.index_key(cfg, 5, tmp_path, mods)
+    assert key != system.index_key(cfg, 5, tmp_path)
+    for m in mods:
+        m.write_text("x = 2\n")
+        assert system.index_key(cfg, 5, tmp_path, mods) != key, m.name
+        m.write_text("x = 1\n")
+    assert system.index_key(cfg, 5, tmp_path, mods) == key
+
+
+def test_stored_index_loads_and_a_broken_one_is_rebuilt(tiny_root):
+    cell = load_cell("tiny.safe", tiny_root / "BENCHMARK.json", tiny_root / "traffic")
+    corpus = gen.make_corpus(cell.config, 9)
+    order, ends = gen.arrange(corpus)
     lines = []
-    args = (cfg, cfg_file, corpus, order, ends, 9, REPO, tmp_path / "index", lines.append)
+    index_dir = tiny_root / "index"
+    args = (cell, corpus, order, ends, 9, REPO, index_dir, lines.append)
     built, how, _ = system.get_index(*args)
     assert how == "built" and not lines
     loaded, how, _ = system.get_index(*args)
     assert how == "loaded"
     assert np.array_equal(loaded.docs, built.docs) and np.array_equal(loaded.impacts, built.impacts)
-    (stored,) = (tmp_path / "index").iterdir()
+    (stored,) = index_dir.iterdir()
     (stored / "manifest.json").write_text("not json")
     again, how, _ = system.get_index(*args)
     assert how == "built" and np.array_equal(again.impacts, built.impacts)

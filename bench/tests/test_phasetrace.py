@@ -150,3 +150,15 @@ def test_recorded_chip_trace_with_phases_and_scopes():
     assert sum(r["idle_by_owner"].values()) <= r["window_s"] - r["busy_s"]
     assert r["step_idle_named_s"] >= 0.9 * r["step_idle_s"] > 0
     assert set(phasetrace.per_dispatch(r)) == SCOPE_METRICS | PHASE_METRICS
+
+
+@pytest.mark.parametrize("name", sorted(SCOPE_METRICS | PHASE_METRICS))
+def test_each_phase_and_scope_reader_reads_the_recorded_chip_trace(name):
+    from types import SimpleNamespace
+
+    from bench.spec import metric_reader
+
+    r = phasetrace.reduce(phasetrace.read_events(DATA / "trace_events_phases.json"), PROGRAM)
+    got = metric_reader(name)(SimpleNamespace(trace=r))
+    assert got == phasetrace.per_dispatch(r)[name] and got >= 0
+    assert metric_reader(name)(SimpleNamespace(trace=None)) is None

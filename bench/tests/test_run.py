@@ -102,7 +102,7 @@ def test_certified_check_holds_a_budget_exit_to_its_ranges():
 
     cfg = tiny_config()
     c = gen.make_corpus(cfg, 1)
-    order, ends = gen.topic_arrangement(c)
+    order, ends = gen.arrange(c)
     ref = Reference(c, order, ends, 10, 8, 0.4, 0.9)
     for q in gen.make_queries(c, tiny_traffic(), 20, 1):
         full = ref.topk(ref.scores(q))

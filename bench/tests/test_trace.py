@@ -58,3 +58,17 @@ def test_recorded_chip_trace():
         r["window_s"] - r["busy_s"], rel=1e-6
     )
     assert len(r["device_ops"]) <= 10 and len(r["idle_gaps"]) <= 10
+
+
+def test_traversal_sums_the_layout_programs_and_counts_the_first():
+    events = [
+        ev("python", "bench.window", 0, 1000, "/host:CPU"),
+        ev("XLA Modules", "jit_step_program(1)", 100, 300),
+        ev("XLA Modules", "jit_merge_program(1)", 300, 350),
+        ev("XLA Modules", "jit_step_program(2)", 500, 700),
+        ev("XLA Ops", "fusion.1", 100, 700),
+    ]
+    r = devtrace.reduce(events, ("step_program", "merge_program"))
+    assert devtrace.traversal(r) == (pytest.approx(450e-9), 2)
+    assert devtrace.traversal(devtrace.reduce(events, ("absent_program",))) is None
+    assert devtrace.traversal(None) is None
