@@ -44,6 +44,11 @@ METRIC_HELP: dict[str, str] = {
     "phase_ms": "Wall-clock per phase of a server step, by server and phase.",
     "gather_fill": "Percent of the block lanes a dispatch gathered that held "
     "a scored posting: 100 x postings / (slots x quantum x width x 128).",
+    "carry_uploads": "Whole in-flight carries uploaded to the device: one a "
+    "server's life.",
+    "carry_resets": "Dispatches that reset admitted or parked lanes of the "
+    "device-resident in-flight carry.",
+    "carry_lanes_reset": "Lanes those resets wrote, by kind (fresh or parked).",
     # ------------------------------------------------------------- sharded
     "sharded_queries": "Queries served through the sharded broker.",
     "shard_exits": "Per-shard exit reasons across sharded queries.",

@@ -177,7 +177,9 @@ class SlotTable:
 
     A cleared (vacant) lane is inert: all ``-1`` blocks, zero bounds, zero
     budget, ``max_ranges = 0`` — the traversal cond fails on the first
-    iteration, so a vacant lane costs nothing per quantum.
+    iteration, so a vacant lane's carry never changes. It is not free:
+    under ``vmap`` the cond is a per-lane select, so the lane is still
+    scored, and the result dropped, on every iteration another lane runs.
     """
 
     def __init__(self, n_slots: int, n_ranges: int, width: int):

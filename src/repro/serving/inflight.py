@@ -13,13 +13,28 @@ fixed range *quantum* at a time via ``range_daat.batched_traverse_resume``.
 A query that exits (safe, budget, or exhausted) frees its slot mid-flight;
 an admitted query from the queue swaps in on the next quantum without
 recompiling and without waiting for its former batchmates. Vacant slots
-ride along parked (``exit_budget`` raised), so the resume loop's condition
-fails before any work and an empty lane costs nothing per dispatch.
+ride along parked (``exit_budget`` raised): the resume loop leaves their
+carry untouched, but under ``vmap`` the loop's condition is a per-lane
+select, so a parked lane is still scored, and its result dropped, on
+every iteration that another lane runs.
+
+The carry stays on the device. The carry a dispatch returns is the next
+dispatch's input; it is uploaded once, when the server is built. Between
+dispatches the host records a per-lane code (keep, fresh or parked) for
+every admission and park, and a step with any lane changed uploads that
+code and runs one small program (``_reset_lanes``) that writes the lanes'
+fresh or parked values. Right after the traversal is dispatched, a second
+small program (``_pack_carry``) lays the carry out as one int32 array, and
+its copy to the host starts then, under the planning that follows; the
+fetch waits on that one array and unpacks it into ``self.carry``, the
+host mirror that retirement reads.
 
 Correctness contract (pinned tier-1 in tests/test_inflight.py): the carry
-round-trips host<->device bitwise, so a query served across N quanta is
-*identical* — doc ids, scores, work counters, exit reason — to the same
-query served by one ``device_traverse`` call.
+every dispatch receives is, leaf for leaf and bitwise, the host mirror —
+the previous dispatch's carry with admitted lanes reset fresh and retired
+lanes parked — so a query served across N quanta is *identical* — doc
+ids, scores, work counters, exit reason — to the same query served by one
+``device_traverse`` call.
 
 Staging is double-buffered (``bucketing.DoubleBuffer``): the front
 ``SlotTable``'s snapshot is what the in-flight dispatch reads, lane writes
@@ -37,6 +52,7 @@ queue-aware feedback applies unchanged here.
 
 from __future__ import annotations
 
+import functools
 from collections import deque
 from typing import Sequence
 
@@ -65,14 +81,65 @@ from repro.serving.microbatch import ServedQuery, SlaBudgeter, result_exit_reaso
 __all__ = ["InflightServer"]
 
 
+# Per-lane codes of what changed in the host mirror since the last dispatch.
+KEEP, FRESH, PARKED = 0, 1, 2
+
+
 def _carry_to_device(carry: TraverseCarry) -> TraverseCarry:
     return jax.tree_util.tree_map(jnp.asarray, carry)
 
 
-def _carry_to_host(carry: TraverseCarry) -> TraverseCarry:
-    # np.array (not asarray): the host copy is mutated in place on lane
-    # admission/parking and must not alias a device buffer.
-    return jax.tree_util.tree_map(lambda x: np.array(x), carry)
+def _lane_values(k: int) -> tuple[TraverseCarry, TraverseCarry]:
+    """One-lane host carries holding a fresh lane's values and a parked one's."""
+    return batched_init_carry(1, k, parked=False), batched_init_carry(1, k, parked=True)
+
+
+@functools.partial(jax.jit, static_argnames="k")
+def _reset_lanes(carry: TraverseCarry, code: jnp.ndarray, *, k: int) -> TraverseCarry:
+    """``carry`` with each ``FRESH`` lane reset fresh and each ``PARKED`` lane parked.
+
+    The values are ``_lane_values``', the ones ``InflightServer`` writes
+    into its host mirror, so the two stay bitwise equal. ``code`` is
+    ``[n]`` over the leaves' leading (lane) axis; ``KEEP`` lanes pass
+    through.
+    """
+    fresh, parked = _lane_values(k)
+
+    def lane(x, f, p):
+        c = code.reshape(code.shape + (1,) * (x.ndim - 1))
+        return jnp.where(c == FRESH, f, jnp.where(c == PARKED, p, x))
+
+    return jax.tree_util.tree_map(lane, carry, fresh, parked)
+
+
+@jax.jit
+def _pack_carry(carry: TraverseCarry) -> jnp.ndarray:
+    """Every leaf of a lane-major carry as int32 columns of one ``[n, 2k+5]`` array."""
+    return jnp.concatenate(
+        [
+            x.reshape(x.shape[0], -1).astype(jnp.int32)
+            for x in jax.tree_util.tree_leaves(carry)
+        ],
+        axis=1,
+    )
+
+
+def _carry_to_host(packed: jnp.ndarray, like: TraverseCarry) -> TraverseCarry:
+    """The step's one device-to-host copy: ``_pack_carry``'s array, unpacked.
+
+    Leaves come back with ``like``'s shapes and dtypes, as views into one
+    host array (bools as casts). np.array (not asarray): the host copy is
+    mutated in place on lane admission/parking and must not alias a
+    device buffer.
+    """
+    flat = np.array(packed)
+    leaves, tree = jax.tree_util.tree_flatten(like)
+    out, col = [], 0
+    for x in leaves:
+        w = x[0].size
+        out.append(flat[:, col : col + w].reshape(x.shape).astype(x.dtype, copy=False))
+        col += w
+    return jax.tree_util.tree_unflatten(tree, out)
 
 
 class InflightServer:
@@ -120,8 +187,15 @@ class InflightServer:
         self.buffers = DoubleBuffer(
             self.n_slots, self.n_ranges, bengine.spec.min_width
         )
-        # Host-resident carry: every lane starts parked (vacant).
-        self.carry = batched_init_carry(self.n_slots, self.engine.k, parked=True)
+        # Every lane starts parked (vacant). The device carry is uploaded
+        # here, once; ``carry`` is its host mirror, refreshed by each fetch.
+        k = self.engine.k
+        self.carry = batched_init_carry(self.n_slots, k, parked=True)
+        self._dcarry = _carry_to_device(self.carry)
+        self._lane_init = _lane_values(k)
+        self._lane_code = np.full(self.n_slots, KEEP, np.int32)
+        if obs.enabled:
+            obs.count("carry_uploads", server="inflight")
 
         self.slot_rid = np.full(self.n_slots, -1, dtype=np.int64)
         self.slot_t_enq = np.zeros(self.n_slots, dtype=np.float64)
@@ -178,13 +252,29 @@ class InflightServer:
         return int(min(int(b[0]), INT32_MAX))
 
     def _reset_carry_lane(self, lane: int, parked: bool) -> None:
-        self.carry.i[lane] = 0
-        self.carry.state.vals[lane] = 0
-        self.carry.state.ids[lane] = -1
-        self.carry.state.postings[lane] = 0
-        self.carry.state.blocks[lane] = 0
-        self.carry.exit_safe[lane] = False
-        self.carry.exit_budget[lane] = parked
+        """Reset ``lane`` in the host mirror; the next launch does it on the device."""
+        values = self._lane_init[int(parked)]
+        for x, v in zip(jax.tree_util.tree_leaves(self.carry), jax.tree_util.tree_leaves(values)):
+            x[lane] = v[0]
+        self._lane_code[lane] = PARKED if parked else FRESH
+
+    def _reset_device_lanes(self) -> None:
+        """Replay the mirror's lane resets on the device carry, if any lane changed.
+
+        One ``[n_slots]`` upload and one small dispatch; a step with no
+        admission or park since the last dispatch does neither.
+        """
+        code = self._lane_code
+        if not code.any():
+            return
+        self._dcarry = _reset_lanes(self._dcarry, jnp.asarray(code), k=self.engine.k)
+        self._lane_code = np.full(self.n_slots, KEEP, np.int32)
+        if self.obs.enabled:
+            self.obs.count("carry_resets", server="inflight")
+            for kind, c in (("fresh", FRESH), ("parked", PARKED)):
+                lanes = int((code == c).sum())
+                if lanes:
+                    self.obs.count("carry_lanes_reset", lanes, server="inflight", kind=kind)
 
     def _admit(self, lane: int, rid: int, plan: QueryPlan, t_enq: float) -> None:
         width = self.bengine.spec.width_bucket(plan.blk_tab.shape[1])
@@ -256,7 +346,7 @@ class InflightServer:
                     cache0 = jit_cache_size(batched_traverse_resume)
                 t0 = self.clock()
                 blk, rest, order, bounds, budget, maxr = front.device_arrays()
-                carry = _carry_to_device(self.carry)
+                self._reset_device_lanes()
             with obs.phase("dispatch", server="inflight", step=n):
                 out = batched_traverse_resume(
                     eng.dix,
@@ -266,13 +356,18 @@ class InflightServer:
                     bounds,
                     budget,
                     maxr,
-                    carry,
+                    self._dcarry,
                     s_pad=eng.s_pad,
                     k=eng.k,
                     quantum=self.quantum,
                     impl=eng.impl,
                     docs_format=eng.docs_format,
                 )
+                self._dcarry = out
+                # Start the carry's one copy home now: it runs as soon as the
+                # device is done, under the planning below.
+                packed = _pack_carry(out)
+                packed.copy_to_host_async()
         self.compiled_shapes.add((self.n_slots, front.width))
         self.steps_run = n
 
@@ -285,9 +380,9 @@ class InflightServer:
             if prof is not None:
                 # Timing-only sync: splits the device wait out of the carry
                 # fetch below. Results are untouched.
-                jax.block_until_ready(out)  # analysis: allow[HOSTSYNC]
+                jax.block_until_ready(packed)  # analysis: allow[HOSTSYNC]
                 t_dev1 = obs.clock()
-            self.carry = _carry_to_host(out)  # blocks until the quantum lands
+            self.carry = _carry_to_host(packed, self.carry)  # blocks until the quantum lands
             t1 = self.clock()
         step_ms = (t1 - t0) * 1e3
 

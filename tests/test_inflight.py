@@ -1,10 +1,12 @@
 """In-flight (slot-swapping) serving loop: bitwise-resume + saturation.
 
 The tentpole invariant, pinned tier-1: a query served across N slot quanta
-via ``batched_traverse_resume`` — including full host<->device carry
-round-trips between quanta, mid-flight slot swaps, and budget exits — is
-*bitwise identical* to the same query served by one ``device_traverse``
-call: same doc ids, scores, work counters, and exit reason.
+via ``batched_traverse_resume`` — including host<->device carry round-trips
+between quanta, mid-flight slot swaps, and budget exits — is *bitwise
+identical* to the same query served by one ``device_traverse`` call: same
+doc ids, scores, work counters, and exit reason. ``InflightServer`` keeps
+the carry on the device; its every dispatch receives, bit for bit, the
+carry the host round trip would have uploaded.
 """
 
 from __future__ import annotations
@@ -507,3 +509,235 @@ def test_gather_fill_is_scored_postings_over_gathered_lanes(quantum):
     scored = hist.mean(server="inflight") * srv.steps_run * lanes / 100.0
     assert scored == pytest.approx(sum(s.result.postings for s in served))
     assert 0.0 < hist.mean(server="inflight") < 100.0
+
+
+# ------------------------------------------------------ device-resident carry
+
+
+class CyclingBudgeter(FixedBudgeter):
+    """Admission ``i`` gets ``caps[i % len(caps)]``: budget and safe exits mixed."""
+
+    def __init__(self, caps):
+        super().__init__()
+        self.caps = list(caps)
+
+    def budgets(self, n, plans=None):
+        out = [self.caps[(len(self.given) + j) % len(self.caps)] for j in range(n)]
+        self.given.extend(out)
+        return np.asarray(out, dtype=np.int32)
+
+
+def _setup_k(k: int):
+    """An engine whose ranges are wide enough for a top-``k`` heap, and 12 queries."""
+    if k <= 10:
+        return _small_setup(seed=31, n_ranges=6, k=k, n_queries=12)
+    corpus = make_corpus(n_docs=8400, n_terms=900, n_topics=4, mean_doc_len=20, seed=31)
+    idx = build_index(corpus, n_ranges=8, strategy="clustered")
+    eng = Engine(idx, k=k)
+    log = make_query_log(corpus, n_queries=12, seed=32)
+    return eng, [log.terms[i] for i in range(log.n_queries)]
+
+
+def _host_lane(k: int, parked: bool) -> dict:
+    """The values a host round trip writes into a fresh or parked lane."""
+    return {
+        "i": np.int32(0), "vals": np.zeros(k, np.int32), "ids": np.full(k, -1, np.int32),
+        "postings": np.int32(0), "blocks": np.int32(0),
+        "exit_safe": False, "exit_budget": parked,
+    }
+
+
+def _named(carry) -> dict:
+    return {
+        "i": carry.i, "vals": carry.state.vals, "ids": carry.state.ids,
+        "postings": carry.state.postings, "blocks": carry.state.blocks,
+        "exit_safe": carry.exit_safe, "exit_budget": carry.exit_budget,
+    }
+
+
+def _spy_dispatches(monkeypatch, srv):
+    """Record, per traversal dispatch, its carry argument, the slots' rids
+    and the host mirror then, and its output (host copies and the objects)."""
+    from repro.serving import inflight
+
+    real = inflight.batched_traverse_resume
+    calls = []
+
+    def spy(*args, **kw):
+        carry = args[7]
+        out = real(*args, **kw)
+        calls.append(dict(
+            arg=carry, out=out, rid=srv.slot_rid.copy(),
+            host_in=_to_host(carry), mirror=_to_host(srv.carry), host_out=_to_host(out),
+        ))
+        return out
+
+    monkeypatch.setattr(inflight, "batched_traverse_resume", spy)
+    return calls
+
+
+def _round_trip_inputs(calls, n_slots: int, k: int):
+    """Per dispatch, the carry the host round trip would upload: the previous
+    output fetched, lanes holding a new query reset fresh, vacant lanes parked;
+    and each dispatch's (fresh, parked) lane counts."""
+    prev = _named(batched_init_carry(n_slots, k, parked=True))
+    prev_rid = np.full(n_slots, -1)
+    expected, changes = [], []
+    for c in calls:
+        exp = {name: np.array(v) for name, v in prev.items()}
+        fresh = parked = 0
+        for lane in range(n_slots):
+            rid = c["rid"][lane]
+            if rid >= 0 and rid == prev_rid[lane]:
+                continue
+            if rid >= 0:
+                fresh += 1
+            elif prev_rid[lane] >= 0:
+                parked += 1
+            for name, v in _host_lane(k, parked=rid < 0).items():
+                exp[name][lane] = v
+        expected.append(exp)
+        changes.append((fresh, parked))
+        prev, prev_rid = _named(c["host_out"]), c["rid"]
+    return expected, changes
+
+
+@pytest.mark.parametrize("k", [10, 1000])
+@pytest.mark.parametrize("quantum", [1, 2])
+def test_every_dispatch_gets_the_round_trip_carry_bitwise(monkeypatch, quantum, k):
+    """The device-resident carry a dispatch receives equals, leaf for leaf and
+    bit for bit, what fetching the last output and resetting the admitted and
+    parked lanes on the host would have uploaded; unchanged steps pass the
+    last output itself, with no upload."""
+    eng, queries = _setup_k(k)
+    caps = [INT32_MAX, 90, INT32_MAX, 400]
+    srv = InflightServer(
+        BatchEngine(eng, BucketSpec(max_batch=4)), CyclingBudgeter(caps),
+        n_slots=3, quantum=quantum,
+    )
+    calls = _spy_dispatches(monkeypatch, srv)
+    served = srv.replay(queries)
+
+    expected, changes = _round_trip_inputs(calls, srv.n_slots, eng.k)
+    assert len(calls) == srv.steps_run
+    for n, (c, exp, ch) in enumerate(zip(calls, expected, changes)):
+        got, mirror = _named(c["host_in"]), _named(c["mirror"])
+        for name, want in exp.items():
+            assert got[name].dtype == want.dtype == mirror[name].dtype, name
+            assert got[name].tobytes() == want.tobytes(), (n, name)
+            assert mirror[name].tobytes() == want.tobytes(), (n, name)
+        if n and ch == (0, 0):
+            assert c["arg"] is calls[n - 1]["out"]  # resident: not uploaded
+        elif n:
+            assert c["arg"] is not calls[n - 1]["out"]  # the reset's output
+
+    # The replay swapped slots mid-flight, ended queries on their budget
+    # and not, and had steps with no lane change.
+    assert any(f and c["rid"][c["rid"] >= 0].size > f for c, (f, _) in zip(calls, changes))
+    reasons = {s.result.exit_reason for s in served}
+    assert "budget" in reasons and reasons - {"budget"}
+    assert any(ch == (0, 0) for ch in changes[1:])
+    for s in served:
+        _assert_result_matches_single(
+            eng, eng.plan(queries[s.rid]), s.result,
+            budget_postings=caps[s.rid % len(caps)],
+        )
+
+
+@pytest.mark.parametrize("k", [10, 1000])
+def test_pack_carry_round_trips_through_host(k):
+    """``_pack_carry`` then the host unpack returns every leaf bit for bit,
+    both exit flags set included, from one ``[n, 2k+5]`` int32 array."""
+    from repro.serving.inflight import _carry_to_host, _pack_carry
+
+    n = 5
+    rng = np.random.default_rng(k)
+    like = batched_init_carry(n, k)
+    host = jax.tree_util.tree_map(
+        lambda x: rng.integers(0, 2, x.shape).astype(bool) if x.dtype == bool
+        else rng.integers(-(2**31), 2**31 - 1, x.shape, dtype=np.int64).astype(np.int32),
+        like,
+    )
+    host.exit_safe[:2] = True
+    host.exit_budget[:2] = True
+    packed = _pack_carry(_to_device(host))
+    assert packed.shape == (n, 2 * k + 5) and packed.dtype == jnp.int32
+    back = _carry_to_host(packed, like)
+    assert jax.tree_util.tree_structure(back) == jax.tree_util.tree_structure(host)
+    for a, b in zip(jax.tree_util.tree_leaves(host), jax.tree_util.tree_leaves(back)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+    assert back.exit_safe[:2].all() and back.exit_budget[:2].all()
+
+
+@pytest.mark.parametrize("quantum", [1, 2])
+def test_carry_uploaded_once_and_fetched_once_a_step(monkeypatch, quantum):
+    """One whole-carry upload a server's life; each step packs its carry
+    once and brings it to the host in one array, inside ``_carry_to_host``."""
+    from repro.obs import Instrumentation
+    from repro.serving import inflight
+
+    counts = {"up": 0, "pack": 0}
+    fetched = []
+    real_up, real_pack, real_fetch = (
+        inflight._carry_to_device, inflight._pack_carry, inflight._carry_to_host
+    )
+
+    def up(carry):
+        counts["up"] += 1
+        return real_up(carry)
+
+    def pack(carry):
+        counts["pack"] += 1
+        return real_pack(carry)
+
+    def fetch(packed, like):
+        fetched.append(packed)
+        return real_fetch(packed, like)
+
+    monkeypatch.setattr(inflight, "_carry_to_device", up)
+    monkeypatch.setattr(inflight, "_pack_carry", pack)
+    monkeypatch.setattr(inflight, "_carry_to_host", fetch)
+
+    eng, queries = _small_setup(seed=33, n_ranges=6, n_queries=10)
+    obs = Instrumentation.make()
+    srv = InflightServer(
+        BatchEngine(eng, BucketSpec(max_batch=4)), FixedBudgeter(),
+        n_slots=4, quantum=quantum, obs=obs,
+    )
+    srv.replay(queries)
+    assert srv.steps_run > 1
+    assert counts["up"] == 1
+    assert obs.metrics.counter("carry_uploads").value(server="inflight") == 1
+    assert counts["pack"] == len(fetched) == srv.steps_run
+    for packed in fetched:
+        assert isinstance(packed, jax.Array)
+        assert packed.shape == (srv.n_slots, 2 * eng.k + 5)
+    for leaf in jax.tree_util.tree_leaves(srv.carry):
+        assert type(leaf) is np.ndarray  # the mirror holds no device buffer
+
+
+@pytest.mark.parametrize("quantum", [1, 2])
+def test_carry_reset_counters_follow_lane_changes(monkeypatch, quantum):
+    """``carry_resets`` counts the dispatches with an admitted or parked lane,
+    and ``carry_lanes_reset`` the lanes of each kind those resets wrote."""
+    from repro.obs import Instrumentation
+
+    eng, queries = _small_setup(seed=34, n_ranges=6, n_queries=12)
+    obs = Instrumentation.make()
+    srv = InflightServer(
+        BatchEngine(eng, BucketSpec(max_batch=4)), CyclingBudgeter([INT32_MAX, 80]),
+        n_slots=3, quantum=quantum, obs=obs,
+    )
+    calls = _spy_dispatches(monkeypatch, srv)
+    srv.replay(queries)
+
+    _, changes = _round_trip_inputs(calls, srv.n_slots, eng.k)
+    changed = [ch for ch in changes if ch != (0, 0)]
+    assert 0 < len(changed) < srv.steps_run
+    resets = obs.metrics.counter("carry_resets").value(server="inflight")
+    lanes = obs.metrics.counter("carry_lanes_reset")
+    assert resets == len(changed)
+    assert lanes.value(server="inflight", kind="fresh") == sum(f for f, _ in changes)
+    assert lanes.value(server="inflight", kind="fresh") == srv.admissions == len(queries)
+    assert lanes.value(server="inflight", kind="parked") == sum(p for _, p in changes) > 0

@@ -161,6 +161,29 @@ def test_inflight_resume_step_compiles_with_pallas_scorer(one_chip, compiled_ker
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("k", [K, 1000])
+def test_inflight_carry_reset_and_pack_compile_for_v5e(one_chip, compiled_kernels, k):
+    """The two small programs around each in-flight dispatch: the lane reset
+    of the device-resident carry and its pack into one int32 array."""
+    from repro.serving.inflight import _pack_carry, _reset_lanes
+
+    s = _spec(one_chip)
+    carry = TraverseCarry(
+        i=s((SLOTS,)),
+        state=TopKState(
+            vals=s((SLOTS, k)), ids=s((SLOTS, k)),
+            postings=s((SLOTS,)), blocks=s((SLOTS,)),
+        ),
+        exit_safe=s((SLOTS,), jnp.bool_),
+        exit_budget=s((SLOTS,), jnp.bool_),
+    )
+    reset = _reset_lanes.lower(carry, s((SLOTS,)), k=k).compile()
+    out = jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), reset.out_info)
+    assert out == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), carry)
+    packed = _pack_carry.lower(carry).compile().out_info
+    assert (packed.shape, packed.dtype) == ((SLOTS, 2 * k + 5), jnp.int32)
+
+
 @pytest.mark.parametrize("replicas,shards", [(1, 4), (2, 2)])
 def test_mesh_dispatch_compiles_for_v5e_2x2(topo, compiled_kernels, replicas, shards):
     """The four-chip broker step: index placed one shard per device."""
